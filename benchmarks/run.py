@@ -10,7 +10,8 @@ trajectory is machine-trackable across PRs.
                      sequential loop vs natively batched blocked FW vs the
                      fused round's native batch grid vs a warm ApspEngine
                      cache
-  fw_dist          — distributed FW ladder (subprocess, 8 host devices):
+  fw_dist          — distributed FW ladder (in-process on the real devices;
+                     a subprocess on 8 virtual host devices on the CPU):
                      per-round ms for the fused bordered round vs the
                      per-phase lowering, whole-solve wall, and the
                      measured-vs-model SUMMA comm efficiency (collective
@@ -158,13 +159,24 @@ DIST_NDEV, DIST_N, DIST_BS = 8, 512, 64
 
 
 def _dist_metrics(backend: str) -> dict:
-    """Run fw_dist_check --bench in a subprocess and parse its METRICS line.
+    """``fw_dist_check.bench_metrics`` for the distributed ladder.
 
-    Subprocess because the XLA host-device count is locked at first jax
-    init; the main benchmark process must keep seeing one device.
+    On an accelerator it runs in this process on the real devices (one
+    process holds the chips).  On the CPU it runs ``fw_dist_check --bench``
+    in a subprocess on DIST_NDEV virtual host devices, because the host
+    device count is locked at first jax init and this process keeps one.
     """
+    if jax.default_backend() != "cpu":
+        from repro.core.semiring import MIN_PLUS
+        from repro.launch.fw_dist_check import _graph_for, bench_metrics
+        from repro.launch.mesh import make_host_mesh
+
+        w = jnp.asarray(_graph_for("min_plus", DIST_N, seed=0))
+        return bench_metrics(make_host_mesh(), w, MIN_PLUS, bs=DIST_BS,
+                             backend=backend)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)
     res = subprocess.run(
         [sys.executable, "-m", "repro.launch.fw_dist_check",
@@ -201,9 +213,9 @@ def bench_fw_dist():
     and the fused-vs-phases ratio are the portable signals.
     """
     rows = []
-    params = f"ndev={DIST_NDEV},n={DIST_N},bs={DIST_BS}"
     fused = _dist_metrics("fused")
     phases = _dist_metrics("jnp")
+    params = f"ndev={fused['ndev']},n={DIST_N},bs={DIST_BS}"
     rows.append((f"fw_dist/round_ms_fused", params, fused["round_ms"] * 1e3,
                  f"{fused['rounds']}rounds,1disp/round"))
     rows.append((f"fw_dist/round_ms_phases", params, phases["round_ms"] * 1e3,
@@ -829,6 +841,9 @@ def smoke() -> None:
 
 
 def main() -> None:
+    from repro.utils.compat import enable_compile_cache
+
+    enable_compile_cache()
     if "--smoke" in sys.argv[1:]:
         smoke()
         return

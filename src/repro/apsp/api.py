@@ -77,6 +77,8 @@ class APSPResult:
     dist: (n, n) or (B, n, n) closure, unpadded.
     succ: next-hop matrix of the same shape (None unless successors=True);
           succ[i, j] = -1 where no i→j path exists.
+    backend: the lowering that ran the staged/fused round ("tpu" | "gpu" |
+          "ref"); None for the other methods.
     """
 
     dist: jax.Array | np.ndarray
@@ -86,6 +88,7 @@ class APSPResult:
     block_size: int | None
     n: int
     padded_n: int
+    backend: str | None = None
 
     @property
     def batched(self) -> bool:
@@ -260,10 +263,19 @@ def _coerce(w, semiring: Semiring, dtype=None):
 
 
 def _pad(w: jax.Array, m: int, semiring: Semiring) -> jax.Array:
-    """Pad (…, n, n) to (…, m, m) with ⊕-identity edges, ⊗-identity diag."""
+    """Pad (…, n, n) to (…, m, m) with ⊕-identity edges, ⊗-identity diag.
+
+    A numpy input pads on the host (the distributed solve then places it
+    straight into its sharding, never whole on one device)."""
     n = w.shape[-1]
     if m == n:
         return w
+    if isinstance(w, np.ndarray):
+        out = np.full(w.shape[:-2] + (m, m), semiring.zero, w.dtype)
+        out[..., :n, :n] = w
+        idx = np.arange(n, m)
+        out[..., idx, idx] = semiring.one
+        return out
     widths = [(0, 0)] * (w.ndim - 2) + [(0, m - n), (0, m - n)]
     out = jnp.pad(w, widths, constant_values=semiring.zero)
     idx = jnp.arange(n, m)
@@ -439,6 +451,7 @@ def solve(
 
     # --- run ------------------------------------------------------------
     succ = None
+    be = None
     if meth == "numpy":
         dist = (
             np.stack([fw_numpy(g) for g in arr]) if batched else fw_numpy(arr)
@@ -453,7 +466,10 @@ def solve(
             # loop with a leading batch dim — no vmap wrapper.
             dist = fw_naive(wj, semiring=sr)
     else:
-        wp = _pad(jnp.asarray(arr), m, sr)
+        if meth == "distributed" and isinstance(arr, np.ndarray):
+            wp = _pad(arr, m, sr)  # host-side: fw_distributed shards it
+        else:
+            wp = _pad(jnp.asarray(arr), m, sr)
         if meth == "blocked":
             if successors:
                 run = lambda x: fw_blocked_with_successors(x, block_size=s)
@@ -506,12 +522,12 @@ def solve(
         else:  # distributed — the fused bordered round, one dispatch/device
             from repro.core.distributed import fw_distributed
 
-            out = fw_distributed(
+            # The result stays sharded over the mesh.
+            dist = fw_distributed(
                 wp, mesh, block_size=s, row_axes=row_axes, col_axes=col_axes,
                 semiring=sr, variant=variant, interpret=interpret,
                 fused_lowering="auto" if interpret is None else "pallas",
             )
-            dist = jnp.asarray(jax.device_get(out))
         dist = dist[..., :n, :n]
         if succ is not None:
             succ = succ[..., :n, :n]
@@ -521,5 +537,5 @@ def solve(
 
     return APSPResult(
         dist=dist, succ=succ, method=meth, semiring=sr.name,
-        block_size=s, n=n, padded_n=m,
+        block_size=s, n=n, padded_n=m, backend=be,
     )

@@ -151,7 +151,7 @@ class ApspEngine:
         validate: bool = True,
         backend: str = "auto",
         interpret: bool | None = None,
-        vmem_budget: int = 128 << 20,
+        vmem_budget: int | None = None,
         mesh=None,
         row_axes="data",
         col_axes="model",

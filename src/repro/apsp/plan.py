@@ -55,17 +55,38 @@ def round_count(n: int, block_size: int) -> int:
     return padded_size(n, block_size) // block_size
 
 
-def auto_block_size(n: int, *, max_block: int = 128) -> int:
+def auto_block_size(
+    n: int, *, max_block: int = 128, min_block: int | None = None
+) -> int:
     """Pick a pivot-tile size for an n-vertex graph.
 
     128 (the paper's sweet spot on our VMEM budget) once n is large enough;
-    below that, the largest power of two <= ~n/4 (floor 16) so padding waste
-    stays bounded (< 33%) while phase 1 still amortizes.
+    below that, the largest power of two <= ~n/4 so padding waste stays
+    bounded (< 33%) while phase 1 still amortizes — but never below
+    ``min_block`` (None → ``compat.min_block_size()``: 128 on TPU, where
+    Mosaic tiles blocks by lanes and small graphs pad up; 16 elsewhere).
     """
+    if min_block is None:
+        min_block = _min_block()
     if n >= max_block * 2:
-        return max_block
+        return max(max_block, min_block)
     s = 1 << max(4, (max(n, 2) - 1).bit_length() - 2)
-    return min(s, max_block)
+    return max(min(s, max_block), min_block)
+
+
+def _min_block() -> int:
+    from repro.utils import compat  # call-time: plan stays import-light
+
+    return compat.min_block_size()
+
+
+def _vmem_budget(vmem_budget: int | None) -> int:
+    """An explicit budget, else the device's (``compat.vmem_limit_bytes``)."""
+    if vmem_budget is not None:
+        return vmem_budget
+    from repro.utils import compat
+
+    return compat.vmem_limit_bytes()
 
 
 def mesh_factorization(devices: int, pods: int = 1) -> tuple[int, int]:
@@ -113,6 +134,13 @@ def dist_round_comm_bytes(
     return batch * (s * s + s * (n // C) + (n // R) * s) * word
 
 
+# (s,s) tiles of live values a fused-round step keeps on Mosaic's stack,
+# which counts against the same scoped-VMEM limit as the scratch bands.
+# Compiled for v5e, a batch block of 22 graphs at n=4096 f32 asked 109.41
+# MiB against the 93.5 MiB of bands + tiles: 11.6 tiles per graph.
+LIVE_TILES = 12
+
+
 def bordered_round_vmem_bytes(
     rows: int, cols: int, s: int, bk: int, *, word: int = 4,
     variant: str = "fori", batch: int = 1,
@@ -121,21 +149,22 @@ def bordered_round_vmem_bytes(
 
     Same shape as ``fused_round_vmem_bytes`` on a rectangular (rows, cols)
     bordered local matrix: the two closed border bands in persistent scratch
-    (s·cols + rows·s words) plus the double-buffered (s,s) in/out tiles,
-    times the batch block.
+    (s·cols + rows·s words) plus the double-buffered (s,s) in/out tiles and
+    the body's live tiles, times the batch block.
     """
     bands = s * cols + rows * s
     tiles = 2 * 2 * s * s
     transient = s * bk * s if variant == "broadcast" else 0
-    return batch * (bands + tiles + transient) * word
+    return batch * (bands + tiles + LIVE_TILES * s * s + transient) * word
 
 
 def auto_bordered_batch_block(
     B: int, rows: int, cols: int, s: int, bk: int, *, word: int = 4,
-    variant: str = "fori", vmem_budget: int = 128 << 20,
+    variant: str = "fori", vmem_budget: int | None = None,
 ) -> int:
     """Largest divisor of B whose bordered scratch bands fit VMEM — the one
     fitting loop shared by ``distributed_plan`` and the kernel wrapper."""
+    vmem_budget = _vmem_budget(vmem_budget)
     for bb in range(B, 0, -1):
         if B % bb:
             continue
@@ -157,7 +186,7 @@ def distributed_plan(
     word: int = 4,
     bk: int = 32,
     variant: str = "fori",
-    vmem_budget: int = 128 << 20,
+    vmem_budget: int | None = None,
 ) -> dict:
     """THE mesh-aware plan for a distributed solve — (R, C, s) + padding.
 
@@ -192,10 +221,11 @@ def distributed_plan(
         # <33% waste bound no longer holds at its preferred tile; walk the
         # tile down until the *mesh* padding respects the same bound (the
         # fattest such tile wins), falling back to the least-padding
-        # candidate when even s=16 cannot (tiny n on a wide mesh).
+        # candidate when even the floor tile cannot (tiny n on a wide mesh).
         cands = []
         s = auto_block_size(n)
-        while s >= 16:
+        floor = _min_block()
+        while s >= floor:
             cands.append((s, padded_size(n, distributed_multiple(s, R, C))))
             s //= 2
         fitting = [(sc, mc) for sc, mc in cands if 3 * (mc - n) <= n]
@@ -229,7 +259,7 @@ def distributed_plan(
 
 
 # Per-SM shared memory of an A100/H100-class part — the GPU analogue of the
-# 128 MB VMEM budget.  The paper's whole contribution is trimming this very
+# TPU VMEM budget.  The paper's whole contribution is trimming this very
 # working set so more blocks co-reside per SM; the occupancy field of the
 # GPU candidates is that trade made explicit.
 GPU_SMEM_BUDGET = 164 << 10
@@ -296,7 +326,8 @@ def fused_round_vmem_bytes(
 
     Persistent scratch holds both closed pivot bands (2·s·n words); the
     (s,s) input and output tiles are each double-buffered by the Pallas
-    pipeline.  The "broadcast" phase-3 variant additionally materializes an
+    pipeline; the kernel body's live values take ``LIVE_TILES`` more (s,s)
+    tiles.  The "broadcast" phase-3 variant additionally materializes an
     (s, bk, s) product transient.  ``batch`` is the batch *block* of the
     batched grid: every term carries a per-graph leading dim, so the
     footprint scales linearly.  See EXPERIMENTS.md §Fused round.
@@ -304,7 +335,7 @@ def fused_round_vmem_bytes(
     bands = 2 * s * n
     tiles = 2 * 2 * s * s
     transient = s * bk * s if variant == "broadcast" else 0
-    return batch * (bands + tiles + transient) * word
+    return batch * (bands + tiles + LIVE_TILES * s * s + transient) * word
 
 
 def fused_round_hbm_bytes(
@@ -439,7 +470,7 @@ def auto_batch_block(
     bk: int = 32,
     word: int = 4,
     variant: str = "fori",
-    vmem_budget: int = 128 << 20,
+    vmem_budget: int | None = None,
     successors: bool = False,
 ) -> int:
     """Largest divisor of B whose per-step scratch+tile footprint fits VMEM.
@@ -452,6 +483,7 @@ def auto_batch_block(
     """
     if B < 1:
         raise ValueError(f"batch size must be >= 1, got {B}")
+    vmem_budget = _vmem_budget(vmem_budget)
     scale = 2 if successors else 1
     for bb in range(B, 0, -1):
         if B % bb:
@@ -468,7 +500,7 @@ def fw_candidates(
     *,
     backend: str = "tpu",
     batch: int = 1,
-    vmem_budget: int = 128 << 20,
+    vmem_budget: int | None = None,
     smem_budget: int = GPU_SMEM_BUDGET,
     word: int | None = None,
     dtype=None,
@@ -484,8 +516,8 @@ def fw_candidates(
     Covers both round lowerings: ``impl="fused"`` (one dispatch/round; bm =
     bn = block_size by construction) and ``impl="staged"`` (4 dispatches;
     bm/bn from the phase-3 tile grid).  A candidate survives iff its
-    per-step VMEM footprint fits ``vmem_budget`` (default: a 128 MB v5e
-    core).  ``batch > 1`` models the batched grid: fused candidates gain a
+    per-step VMEM footprint fits ``vmem_budget`` (default: the device's
+    scoped-VMEM limit, ``compat.vmem_limit_bytes``).  ``batch > 1`` models the batched grid: fused candidates gain a
     ``batch_block`` (the fattest divisor of ``batch`` the budget admits)
     and per-round HBM/step counts scale to the whole batch.  Deterministic
     — the benchmark key manifest is derived from it.
@@ -526,6 +558,7 @@ def fw_candidates(
     """
     if word is None:
         word = word_for(dtype)
+    vmem_budget = _vmem_budget(vmem_budget)
     if lanes < 1:
         raise ValueError(f"lanes must be >= 1, got {lanes}")
     if backend not in ("tpu", "gpu", "ref"):
@@ -535,6 +568,9 @@ def fw_candidates(
         )
     if hbm_budget is not None:
         include_recursive = True
+    if backend == "tpu":
+        # Mosaic's lane tile: sub-128 pivots do not compile for the chip.
+        block_sizes = tuple(s for s in block_sizes if s % _min_block() == 0)
     out = []
     if backend != "tpu":
         for s in block_sizes:
@@ -686,7 +722,7 @@ def autotune_fw(
     *,
     backend: str = "tpu",
     batch: int = 1,
-    vmem_budget: int = 128 << 20,
+    vmem_budget: int | None = None,
     smem_budget: int = GPU_SMEM_BUDGET,
     dtype=None,
     lanes: int = 1,
@@ -720,6 +756,7 @@ def autotune_fw(
     backend (TPU VMEM vs GPU SMEM byte models are not commensurable), and
     the stamp is the per-key provenance the benchmarks persist.
     """
+    vmem_budget = _vmem_budget(vmem_budget)
     cands = fw_candidates(n, backend=backend, batch=batch,
                           vmem_budget=vmem_budget, smem_budget=smem_budget,
                           dtype=dtype, lanes=lanes, variant=variant,

@@ -486,7 +486,9 @@ def fw_distributed(
         batched=batched,
     )
     step = jax.jit(sharded, static_argnames=(), donate_argnums=(0,))
-    wl = jax.device_put(jnp.asarray(w), sharding)
+    # Host arrays go straight to their shards; nothing lands whole on one
+    # device first.
+    wl = jax.device_put(w, sharding)
     b = start_round
     while b < rounds:
         todo = min(rounds_per_call, rounds - b)

@@ -12,22 +12,20 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.semiring import MIN_PLUS, Semiring
+from repro.utils import compat
 
 
 def _phase1_kernel(w_ref, o_ref, *, semiring: Semiring):
-    s = w_ref.shape[-1]
-    t = w_ref[...]
+    from repro.kernels.fw_round import _close_diag  # core imports this module
 
-    def body(k, t):
-        # Ellipsis-relative indexing: the same chain with or without a
-        # leading batch dim ((B,s,s) tiles from the batched grid).
-        return semiring.add(t, semiring.mul(t[..., :, k, None], t[..., k, None, :]))
-
-    o_ref[...] = jax.lax.fori_loop(0, s, body, t)
+    # Ellipsis-relative chain: the same with or without a leading batch dim
+    # ((B,s,s) tiles from the batched grid).
+    o_ref[...] = _close_diag(
+        w_ref[...], w_ref.shape[-1], semiring, mosaic=True
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("semiring", "interpret"))
@@ -48,6 +46,7 @@ def fw_phase1(
             kern,
             out_shape=jax.ShapeDtypeStruct((s, s), tile.dtype),
             interpret=interpret,
+            compiler_params=compat.tpu_compiler_params(dimension_semantics=()),
         )(tile)
     B = tile.shape[0]
     return pl.pallas_call(
@@ -57,4 +56,7 @@ def fw_phase1(
         in_specs=[pl.BlockSpec((1, s, s), lambda g: (g, 0, 0))],
         out_specs=pl.BlockSpec((1, s, s), lambda g: (g, 0, 0)),
         interpret=interpret,
+        compiler_params=compat.tpu_compiler_params(
+            dimension_semantics=("parallel",)
+        ),
     )(tile)

@@ -14,31 +14,27 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.semiring import MIN_PLUS, Semiring
 from repro.kernels.minplus_matmul import _fit_block
+from repro.utils import compat
 
 
 def _row_kernel(d_ref, p_ref, o_ref, *, semiring: Semiring):
-    s = d_ref.shape[-1]
-    d = d_ref[...]
+    from repro.kernels.fw_round import _close_row_panel  # import cycle
 
-    def body(k, p):
-        return semiring.add(p, semiring.mul(d[..., :, k, None], p[..., k, None, :]))
-
-    o_ref[...] = jax.lax.fori_loop(0, s, body, p_ref[...])
+    o_ref[...] = _close_row_panel(
+        p_ref[...], d_ref[...], d_ref.shape[-1], semiring, mosaic=True
+    )
 
 
 def _col_kernel(d_ref, p_ref, o_ref, *, semiring: Semiring):
-    s = d_ref.shape[-1]
-    d = d_ref[...]
+    from repro.kernels.fw_round import _close_col_panel  # import cycle
 
-    def body(k, p):
-        return semiring.add(p, semiring.mul(p[..., :, k, None], d[..., k, None, :]))
-
-    o_ref[...] = jax.lax.fori_loop(0, s, body, p_ref[...])
+    o_ref[...] = _close_col_panel(
+        p_ref[...], d_ref[...], d_ref.shape[-1], semiring, mosaic=True
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("bt", "semiring", "interpret"))
@@ -72,6 +68,9 @@ def fw_phase2_row(
             ],
             out_specs=pl.BlockSpec((s, bt), lambda j: (0, j)),
             interpret=interpret,
+            compiler_params=compat.tpu_compiler_params(
+                dimension_semantics=("parallel",)
+            ),
         )(diag, band)
     B = band.shape[0]
     return pl.pallas_call(
@@ -84,6 +83,9 @@ def fw_phase2_row(
         ],
         out_specs=pl.BlockSpec((1, s, bt), lambda g, j: (g, 0, j)),
         interpret=interpret,
+        compiler_params=compat.tpu_compiler_params(
+            dimension_semantics=("parallel", "parallel")
+        ),
     )(diag, band)
 
 
@@ -114,6 +116,9 @@ def fw_phase2_col(
             ],
             out_specs=pl.BlockSpec((bt, s), lambda i: (i, 0)),
             interpret=interpret,
+            compiler_params=compat.tpu_compiler_params(
+                dimension_semantics=("parallel",)
+            ),
         )(diag, band)
     B = band.shape[0]
     return pl.pallas_call(
@@ -126,4 +131,7 @@ def fw_phase2_col(
         ],
         out_specs=pl.BlockSpec((1, bt, s), lambda g, i: (g, i, 0)),
         interpret=interpret,
+        compiler_params=compat.tpu_compiler_params(
+            dimension_semantics=("parallel", "parallel")
+        ),
     )(diag, band)

@@ -64,6 +64,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.semiring import MIN_PLUS, Semiring
+from repro.kernels.minplus_matmul import _col
 from repro.utils import compat
 
 
@@ -88,8 +89,12 @@ def encode_weights(w, dtype) -> jax.Array:
 
 
 def _decode_weight(wb: jax.Array, dtype) -> jax.Array:
-    """int32 bit pattern → scalar weight in the matrix dtype (bit-exact)."""
+    """int32 bit pattern → (1, 1) weight in the matrix dtype (bit-exact).
+
+    The pattern is broadcast to a vector first: Mosaic bitcasts vectors,
+    not scalars."""
     dt = jnp.dtype(dtype)
+    wb = jnp.full((1, 1), wb, jnp.int32)
     if dt == jnp.dtype(jnp.float32):
         return jax.lax.bitcast_convert_type(wb, jnp.float32)
     if dt == jnp.dtype(jnp.bfloat16):
@@ -99,105 +104,110 @@ def _decode_weight(wb: jax.Array, dtype) -> jax.Array:
     return wb
 
 
+def _lane_pick(ref, k, n: int) -> jax.Array:
+    """Column k of a band ref as an (s, 1) operand without a gather: load
+    the lane tile that holds column k, then rotate k to lane 0 (exact)."""
+    w = 128 if n % 128 == 0 else n
+    base = pl.multiple_of((k // w) * w, w)
+    return _col(ref[:, pl.ds(base, w)], k % w, mosaic=True)
+
+
 def _repair_kernel(order_ref, u_ref, v_ref, wb_ref, d_ref, o_ref, scr_ref,
                    *, n, s, E, semiring):
     g = pl.program_id(0)
     dtype = o_ref.dtype
-
-    def correction(e2, r, limit):
-        """r ⊕= (r[u_e2] ⊗ w_e2) ⊗ scratch[e2], masked to e2 < limit.
-
-        The masked trips read scratch rows that are not yet (or never)
-        staged — garbage values whose results ``jnp.where`` discards.
-        """
-        w2 = _decode_weight(wb_ref[e2], dtype)
-        prow = pl.load(scr_ref, (pl.dslice(e2, 1), slice(None)))  # (1, n)
-        ru = jax.lax.dynamic_slice(r, (0, u_ref[e2]), (1, 1))
-        cand = semiring.mul(semiring.mul(ru, w2), prow)
-        return jnp.where(e2 < limit, semiring.add(r, cand), r)
+    row_g = pl.ds(g, 1)
+    # Stage steps copy their band out byte-identically (see module doc);
+    # apply steps evolve it in place.
+    o_ref[...] = d_ref[...]
 
     @pl.when(g < E)
     def _stage():
-        band = d_ref[...]            # (s, n) row band holding pivot row v_g
-        o_ref[...] = band            # byte-identical copy-out (see module doc)
-        row0 = order_ref[g] * s
-        r = jax.lax.dynamic_slice(band, (v_ref[g] - row0, 0), (1, n))
-        r = jax.lax.fori_loop(
-            0, E, lambda e2, r: correction(e2, r, g), r
-        )
-        pl.store(scr_ref, (pl.dslice(g, 1), slice(None)), r)
+        # Scratch row g holds pivot row v_g as it evolves: r ⊕= (r[u_e2] ⊗
+        # w_e2) ⊗ scratch[e2], masked to e2 < g.  The masked trips read
+        # scratch rows that are not yet (or never) staged — garbage values
+        # whose results ``jnp.where`` discards.
+        scr_ref[row_g, :] = d_ref[pl.ds(v_ref[g] - order_ref[g] * s, 1), :]
+
+        def correction(e2, carry):
+            w2 = _decode_weight(wb_ref[e2], dtype)
+            r = scr_ref[row_g, :]
+            ru = _col(r, u_ref[e2], mosaic=True)
+            cand = semiring.mul(
+                semiring.mul(ru, w2), scr_ref[pl.ds(e2, 1), :]
+            )
+            scr_ref[row_g, :] = jnp.where(e2 < g, semiring.add(r, cand), r)
+            return carry
+
+        jax.lax.fori_loop(0, E, correction, 0)
 
     @pl.when(g >= E)
     def _apply():
-        c = d_ref[...]               # (s, n) band t = g - E
-
-        def body(e2, c):
+        # Band t = g - E folds in all E updates in order.
+        def body(e2, carry):
             w2 = _decode_weight(wb_ref[e2], dtype)
-            prow = pl.load(scr_ref, (pl.dslice(e2, 1), slice(None)))
-            du = jax.lax.dynamic_slice(c, (0, u_ref[e2]), (s, 1))
-            cand = semiring.mul(semiring.mul(du, w2), prow)
-            return semiring.add(c, cand)
+            du = _lane_pick(o_ref, u_ref[e2], n)
+            cand = semiring.mul(
+                semiring.mul(du, w2), scr_ref[pl.ds(e2, 1), :]
+            )
+            o_ref[...] = semiring.add(o_ref[...], cand)
+            return carry
 
-        o_ref[...] = jax.lax.fori_loop(0, E, body, c)
+        jax.lax.fori_loop(0, E, body, 0)
 
 
 def _repair_succ_kernel(order_ref, u_ref, v_ref, wb_ref, d_ref, s_ref,
                         od_ref, os_ref, scrd_ref, scrs_ref, *, n, s, E):
     g = pl.program_id(0)
     dtype = od_ref.dtype
+    row_g = pl.ds(g, 1)
+    od_ref[...] = d_ref[...]
+    os_ref[...] = s_ref[...]
 
     @pl.when(g < E)
     def _stage():
-        band_d = d_ref[...]
-        band_s = s_ref[...]
-        od_ref[...] = band_d
-        os_ref[...] = band_s
-        row0 = order_ref[g] * s
         v_g = v_ref[g]
-        r = jax.lax.dynamic_slice(band_d, (v_g - row0, 0), (1, n))
-        rs = jax.lax.dynamic_slice(band_s, (v_g - row0, 0), (1, n))
+        row = pl.ds(v_g - order_ref[g] * s, 1)
+        scrd_ref[row_g, :] = d_ref[row, :]
+        scrs_ref[row_g, :] = s_ref[row, :]
 
         def correction(e2, carry):
-            r, rs = carry
             w2 = _decode_weight(wb_ref[e2], dtype)
             u2, v2 = u_ref[e2], v_ref[e2]
-            prow = pl.load(scrd_ref, (pl.dslice(e2, 1), slice(None)))
-            ru = jax.lax.dynamic_slice(r, (0, u2), (1, 1))
-            cand = (ru + w2) + prow
+            r = scrd_ref[row_g, :]
+            rs = scrs_ref[row_g, :]
+            ru = _col(r, u2, mosaic=True)
+            cand = (ru + w2) + scrd_ref[pl.ds(e2, 1), :]
             better = jnp.logical_and(cand < r, e2 < g)
-            hop = jnp.where(
-                v_g == u2, v2, jax.lax.dynamic_slice(rs, (0, u2), (1, 1))
-            )
-            return jnp.where(better, cand, r), jnp.where(better, hop, rs)
+            hop = jnp.where(v_g == u2, v2, _col(rs, u2, mosaic=True))
+            scrd_ref[row_g, :] = jnp.where(better, cand, r)
+            scrs_ref[row_g, :] = jnp.where(better, hop, rs)
+            return carry
 
-        r, rs = jax.lax.fori_loop(0, E, correction, (r, rs))
-        pl.store(scrd_ref, (pl.dslice(g, 1), slice(None)), r)
-        pl.store(scrs_ref, (pl.dslice(g, 1), slice(None)), rs)
+        jax.lax.fori_loop(0, E, correction, 0)
 
     @pl.when(g >= E)
     def _apply():
-        c = d_ref[...]
-        cs = s_ref[...]
         ridx = order_ref[g] * s + jax.lax.broadcasted_iota(
             jnp.int32, (s, 1), 0
         )
 
         def body(e2, carry):
-            c, cs = carry
             w2 = _decode_weight(wb_ref[e2], dtype)
             u2, v2 = u_ref[e2], v_ref[e2]
-            prow = pl.load(scrd_ref, (pl.dslice(e2, 1), slice(None)))
-            du = jax.lax.dynamic_slice(c, (0, u2), (s, 1))
-            cand = (du + w2) + prow
+            c = od_ref[...]
+            cs = os_ref[...]
+            du = _lane_pick(od_ref, u2, n)
+            cand = (du + w2) + scrd_ref[pl.ds(e2, 1), :]
             better = cand < c
             hop = jnp.where(
-                ridx == u2, v2, jax.lax.dynamic_slice(cs, (0, u2), (s, 1))
+                ridx == u2, v2, _lane_pick(os_ref, u2, n)
             )
-            return jnp.where(better, cand, c), jnp.where(better, hop, cs)
+            od_ref[...] = jnp.where(better, cand, c)
+            os_ref[...] = jnp.where(better, hop, cs)
+            return carry
 
-        c, cs = jax.lax.fori_loop(0, E, body, (c, cs))
-        od_ref[...] = c
-        os_ref[...] = cs
+        jax.lax.fori_loop(0, E, body, 0)
 
 
 def _repair_order(v: jax.Array, T: int, s: int) -> jax.Array:
@@ -249,6 +259,7 @@ def fw_repair(
     s = block_size
     n, E = _check_args(d, u, v, w, s)
     pltpu = compat.pallas_tpu("fw_repair needs pallas TPU scratch + scalar prefetch")
+    compat.check_tpu_lowering(d.dtype, interpret, s)
     T = n // s
     u = jnp.asarray(u, jnp.int32)
     v = jnp.asarray(v, jnp.int32)
@@ -302,6 +313,7 @@ def fw_repair_with_successors(
     if succ.shape != d.shape:
         raise ValueError(f"succ must match d, got {succ.shape} vs {d.shape}")
     pltpu = compat.pallas_tpu("fw_repair_with_successors needs pallas TPU scratch")
+    compat.check_tpu_lowering(d.dtype, interpret, s)
     T = n // s
     u = jnp.asarray(u, jnp.int32)
     v = jnp.asarray(v, jnp.int32)

@@ -39,7 +39,7 @@ the compact (a, n) strip — (2) closes the band with the *same*
 ``_close_diag`` / ``_close_row_panel`` recurrences as the fused round,
 (3) closes the strip's block columns (``_close_col_panel``) and relaxes the
 whole strip against the closed band through the same ``_stage_compute``
-bk-chunk sequence (``_relax_tile``), and (4) strip rows inside the pivot
+bk-chunk sequence (``_stage_chunks``), and (4) strip rows inside the pivot
 block take their band-closed values.  Per-round traffic is (s + 2a)·n words
 against the full round's 2n² — ``plan.repair_del_hbm_bytes`` models the
 crossover ``plan.should_repair_del`` falls back on.
@@ -80,9 +80,8 @@ from repro.kernels.fw_round import (
     _close_diag,
     _close_row_panel,
     _relax_succ,
-    _relax_tile,
 )
-from repro.kernels.minplus_matmul import Variant, _fit_block, _stage_compute
+from repro.kernels.minplus_matmul import Variant, _fit_block, _stage_chunks
 from repro.utils import compat
 
 
@@ -222,7 +221,7 @@ def fw_repair_del_sweep_ref(
         # values (the fused round's col-band splice), then every strip
         # element relaxes through the same bk-chunk sequence.
         A = jax.lax.dynamic_update_slice(A, acol, (0, o))
-        A = _relax_tile(A, acol, band, s, bk, semiring, variant)
+        A = _stage_chunks(A, acol, band, bk, semiring, variant)
         # Strip rows inside the pivot block were closed in the band; their
         # phase-3 value is discarded in favor of the band closure (a value
         # no-op for idempotent ⊕ — the sweep's contract).
@@ -347,32 +346,34 @@ def _sweep_round_kernel(
 
     @pl.when(g == 0)
     def _phase1():
-        t = _close_diag(band_ref[...], s, semiring)
-        pl.store(bscr_ref, (slice(None), pl.dslice(j * s, s)), t)
+        t = _close_diag(band_ref[...], s, semiring, mosaic=True)
+        bscr_ref[:, pl.ds(j * s, s)] = t
         ob_ref[...] = t
         oa_ref[...] = a_ref[...]
 
     @pl.when((g >= 1) & (g < T))
     def _phase2_row():
-        d = pl.load(bscr_ref, (slice(None), pl.dslice(b * s, s)))
-        p = _close_row_panel(band_ref[...], d, s, semiring)
-        pl.store(bscr_ref, (slice(None), pl.dslice(j * s, s)), p)
+        d = bscr_ref[:, pl.ds(b * s, s)]
+        p = _close_row_panel(band_ref[...], d, s, semiring, mosaic=True)
+        bscr_ref[:, pl.ds(j * s, s)] = p
         ob_ref[...] = p
         oa_ref[...] = a_ref[...]
 
     @pl.when((g >= T) & (j == b))
     def _phase2_col():
-        d = pl.load(bscr_ref, (slice(None), pl.dslice(b * s, s)))
-        p = _close_col_panel(a_ref[...], d, s, semiring)
-        pl.store(cscr_ref, (pl.dslice(r * sa, sa), slice(None)), p)
+        d = bscr_ref[:, pl.ds(b * s, s)]
+        p = _close_col_panel(a_ref[...], d, s, semiring, mosaic=True)
+        cscr_ref[pl.ds(r * sa, sa), :] = p
         oa_ref[...] = p
-        ob_ref[...] = pl.load(bscr_ref, (slice(None), pl.dslice(j * s, s)))
+        ob_ref[...] = bscr_ref[:, pl.ds(j * s, s)]
 
     @pl.when((g >= T) & (j != b))
     def _phase3():
-        a = pl.load(cscr_ref, (pl.dslice(r * sa, sa), slice(None)))
-        bb = pl.load(bscr_ref, (slice(None), pl.dslice(j * s, s)))
-        oa_ref[...] = _relax_tile(a_ref[...], a, bb, s, bk, semiring, variant)
+        a = cscr_ref[pl.ds(r * sa, sa), :]
+        bb = bscr_ref[:, pl.ds(j * s, s)]
+        oa_ref[...] = _stage_chunks(
+            a_ref[...], a, bb, bk, semiring, variant, mosaic=True
+        )
         ob_ref[...] = bb
 
 
@@ -417,6 +418,7 @@ def _sweep_round(
     pltpu = compat.pallas_tpu(
         "fw_repair_del needs pallas TPU scratch + scalar prefetch"
     )
+    compat.check_tpu_lowering(band.dtype, interpret, s)
     T = m // s
     Ta = a_pad // sa
     bk = _fit_block(s, bk)

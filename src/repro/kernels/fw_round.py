@@ -94,7 +94,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.semiring import MIN_PLUS, Semiring
-from repro.kernels.minplus_matmul import Variant, _fit_block, _stage_compute
+from repro.kernels.minplus_matmul import (
+    Variant,
+    _col,
+    _fit_block,
+    _row,
+    _stage_chunks,
+)
 from repro.utils import compat
 
 
@@ -146,59 +152,53 @@ def _bordered_order(tr: int, tc: int) -> tuple[jax.Array, jax.Array]:
 # The per-phase ⊕/⊗ chains, factored out of the kernel bodies so the TPU
 # round (_round_kernel below) and the GPU round (kernels/fw_round_gpu.py)
 # run the IDENTICAL per-element op sequence — bit-equality across backends
-# holds by construction, not by parallel maintenance.  All four are
+# holds by construction, not by parallel maintenance.  All of them are
 # ellipsis-indexed: the same chain runs with or without a leading batch dim.
+# ``mosaic=True`` (the TPU kernel bodies) picks rows/columns without gathers
+# (``minplus_matmul._col``/``_row``); the XLA twins index them.  Phase 3 is
+# ``minplus_matmul._stage_chunks``, the bk-chunk sequence of
+# ``semiring_matmul``'s k grid.
 
 
-def _close_diag(t: jax.Array, s: int, semiring: Semiring) -> jax.Array:
+def _close_diag(
+    t: jax.Array, s: int, semiring: Semiring, mosaic: bool = False
+) -> jax.Array:
     """Phase 1: close an (s,s) diagonal tile under k ∈ [0, s)."""
 
     def body(k, t):
         return semiring.add(
-            t, semiring.mul(t[..., :, k, None], t[..., k, None, :])
+            t, semiring.mul(_col(t, k, mosaic), _row(t, k, mosaic))
         )
 
     return jax.lax.fori_loop(0, s, body, t)
 
 
 def _close_row_panel(
-    p: jax.Array, d: jax.Array, s: int, semiring: Semiring
+    p: jax.Array, d: jax.Array, s: int, semiring: Semiring,
+    mosaic: bool = False,
 ) -> jax.Array:
     """Phase 2 (row band): rows live in the pivot block → a-side is ``d``."""
 
     def body(k, p):
         return semiring.add(
-            p, semiring.mul(d[..., :, k, None], p[..., k, None, :])
+            p, semiring.mul(_col(d, k, mosaic), _row(p, k, mosaic))
         )
 
     return jax.lax.fori_loop(0, s, body, p)
 
 
 def _close_col_panel(
-    p: jax.Array, d: jax.Array, s: int, semiring: Semiring
+    p: jax.Array, d: jax.Array, s: int, semiring: Semiring,
+    mosaic: bool = False,
 ) -> jax.Array:
     """Phase 2 (col band): columns live in the pivot block → b-side is ``d``."""
 
     def body(k, p):
         return semiring.add(
-            p, semiring.mul(p[..., :, k, None], d[..., k, None, :])
+            p, semiring.mul(_col(p, k, mosaic), _row(d, k, mosaic))
         )
 
     return jax.lax.fori_loop(0, s, body, p)
-
-
-def _relax_tile(
-    c: jax.Array, a: jax.Array, bb: jax.Array, s: int, bk: int,
-    semiring: Semiring, variant: Variant,
-) -> jax.Array:
-    """Phase 3: relax one tile against the closed bands, bk-chunk staged —
-    the exact ``_stage_compute`` sequence of ``semiring_matmul``'s k grid."""
-    for k0 in range(0, s, bk):
-        c = _stage_compute(
-            c, a[..., :, k0:k0 + bk], bb[..., k0:k0 + bk, :],
-            semiring, variant,
-        )
-    return c
 
 
 def _round_kernel(
@@ -229,37 +229,37 @@ def _round_kernel(
 
     @pl.when(g == 0)
     def _phase1():
-        t = _close_diag(w_ref[...], s, semiring)
+        t = _close_diag(w_ref[...], s, semiring, mosaic=True)
         o_ref[...] = t
         # Seed both scratch bands with the closed diagonal: phase-3 steps can
         # then read A/B slices unconditionally at any tile index, pivot
         # included (the splice fw_staged did with dynamic_update_slice).
-        pl.store(row_ref, lead + (slice(None), pl.dslice(j * s, s)), t)
-        pl.store(col_ref, lead + (pl.dslice(i * s, s), slice(None)), t)
+        row_ref[lead + (slice(None), pl.ds(j * s, s))] = t
+        col_ref[lead + (pl.ds(i * s, s), slice(None))] = t
 
     @pl.when((g >= 1) & (g < tc))
     def _phase2_row():
-        d = pl.load(row_ref, lead + (slice(None), pl.dslice(b * s, s)))
-        p = _close_row_panel(w_ref[...], d, s, semiring)
+        d = row_ref[lead + (slice(None), pl.ds(b * s, s))]
+        p = _close_row_panel(w_ref[...], d, s, semiring, mosaic=True)
         # Owner echo: the tile at border column pc is the device's broadcast
         # copy of the raw diagonal — its closed value is the phase-1 closure,
         # not the phase-2 recurrence (they differ for non-idempotent ⊕).
         p = jnp.where(j == pc, d, p)
         o_ref[...] = p
-        pl.store(row_ref, lead + (slice(None), pl.dslice(j * s, s)), p)
+        row_ref[lead + (slice(None), pl.ds(j * s, s))] = p
 
     @pl.when((g >= tc) & (g < tc + tr - 1))
     def _phase2_col():
-        d = pl.load(row_ref, lead + (slice(None), pl.dslice(b * s, s)))
-        p = _close_col_panel(w_ref[...], d, s, semiring)
+        d = row_ref[lead + (slice(None), pl.ds(b * s, s))]
+        p = _close_col_panel(w_ref[...], d, s, semiring, mosaic=True)
         p = jnp.where(i == pr, d, p)
         o_ref[...] = p
-        pl.store(col_ref, lead + (pl.dslice(i * s, s), slice(None)), p)
+        col_ref[lead + (pl.ds(i * s, s), slice(None))] = p
 
     @pl.when(g >= tc + tr - 1)
     def _phase3():
-        a = pl.load(col_ref, lead + (pl.dslice(i * s, s), slice(None)))
-        bb = pl.load(row_ref, lead + (slice(None), pl.dslice(j * s, s)))
+        a = col_ref[lead + (pl.ds(i * s, s), slice(None))]
+        bb = row_ref[lead + (slice(None), pl.ds(j * s, s))]
         # Accumulator input: pivot-band tiles were rewritten this round, so
         # their current value lives in scratch (== a/bb), not in w_ref; the
         # owner-echo rows/cols are a device's local copies of the same bands.
@@ -267,21 +267,23 @@ def _round_kernel(
             (i == b) | (i == pr), bb,
             jnp.where((j == b) | (j == pc), a, w_ref[...]),
         )
-        o_ref[...] = _relax_tile(c, a, bb, s, bk, semiring, variant)
+        o_ref[...] = _stage_chunks(
+            c, a, bb, bk, semiring, variant, mosaic=True
+        )
 
 
-def _relax_succ(k, t, ts, a, asucc, bb):
+def _relax_succ(k, t, ts, a, asucc, bb, mosaic: bool = False):
     """Strict-improvement relaxation step k, carrying successors.
 
     cand = a[:,k] ⊗ bb[k,:]; where cand < t the distance AND the next hop
     (asucc[:,k]) are taken — the exact update of ``core.paths``, ellipsis-
     indexed so the same chain runs with or without a leading batch dim.
     """
-    cand = a[..., :, k, None] + bb[..., k, None, :]
+    cand = _col(a, k, mosaic) + _row(bb, k, mosaic)
     better = cand < t
     return (
         jnp.where(better, cand, t),
-        jnp.where(better, asucc[..., :, k, None], ts),
+        jnp.where(better, _col(asucc, k, mosaic), ts),
     )
 
 
@@ -307,61 +309,61 @@ def _round_succ_kernel(
     def _phase1():
         def body(k, c):
             t, ts = c
-            return _relax_succ(k, t, ts, t, ts, t)
+            return _relax_succ(k, t, ts, t, ts, t, mosaic=True)
 
         t, ts = jax.lax.fori_loop(0, s, body, (w_ref[...], s_ref[...]))
         ow_ref[...] = t
         os_ref[...] = ts
-        pl.store(rw_ref, lead + (slice(None), pl.dslice(j * s, s)), t)
-        pl.store(cw_ref, lead + (pl.dslice(i * s, s), slice(None)), t)
-        pl.store(rs_ref, lead + (slice(None), pl.dslice(j * s, s)), ts)
-        pl.store(cs_ref, lead + (pl.dslice(i * s, s), slice(None)), ts)
+        rw_ref[lead + (slice(None), pl.ds(j * s, s))] = t
+        cw_ref[lead + (pl.ds(i * s, s), slice(None))] = t
+        rs_ref[lead + (slice(None), pl.ds(j * s, s))] = ts
+        cs_ref[lead + (pl.ds(i * s, s), slice(None))] = ts
 
     @pl.when((g >= 1) & (g < T))
     def _phase2_row():
         # Rows live in the pivot block → the a-side successor operand is the
         # closed diagonal's successor tile.
-        d = pl.load(rw_ref, lead + (slice(None), pl.dslice(b * s, s)))
-        ds = pl.load(rs_ref, lead + (slice(None), pl.dslice(b * s, s)))
+        d = rw_ref[lead + (slice(None), pl.ds(b * s, s))]
+        ds = rs_ref[lead + (slice(None), pl.ds(b * s, s))]
 
         def body(k, c):
             p, ps = c
-            return _relax_succ(k, p, ps, d, ds, p)
+            return _relax_succ(k, p, ps, d, ds, p, mosaic=True)
 
         p, ps = jax.lax.fori_loop(0, s, body, (w_ref[...], s_ref[...]))
         ow_ref[...] = p
         os_ref[...] = ps
-        pl.store(rw_ref, lead + (slice(None), pl.dslice(j * s, s)), p)
-        pl.store(rs_ref, lead + (slice(None), pl.dslice(j * s, s)), ps)
+        rw_ref[lead + (slice(None), pl.ds(j * s, s))] = p
+        rs_ref[lead + (slice(None), pl.ds(j * s, s))] = ps
 
     @pl.when((g >= T) & (g < 2 * T - 1))
     def _phase2_col():
         # Columns k live in the pivot block → the a-side is the panel's own
         # (evolving) distance/successor columns.
-        d = pl.load(rw_ref, lead + (slice(None), pl.dslice(b * s, s)))
+        d = rw_ref[lead + (slice(None), pl.ds(b * s, s))]
 
         def body(k, c):
             p, ps = c
-            return _relax_succ(k, p, ps, p, ps, d)
+            return _relax_succ(k, p, ps, p, ps, d, mosaic=True)
 
         p, ps = jax.lax.fori_loop(0, s, body, (w_ref[...], s_ref[...]))
         ow_ref[...] = p
         os_ref[...] = ps
-        pl.store(cw_ref, lead + (pl.dslice(i * s, s), slice(None)), p)
-        pl.store(cs_ref, lead + (pl.dslice(i * s, s), slice(None)), ps)
+        cw_ref[lead + (pl.ds(i * s, s), slice(None))] = p
+        cs_ref[lead + (pl.ds(i * s, s), slice(None))] = ps
 
     @pl.when(g >= 2 * T - 1)
     def _phase3():
-        a = pl.load(cw_ref, lead + (pl.dslice(i * s, s), slice(None)))
-        asucc = pl.load(cs_ref, lead + (pl.dslice(i * s, s), slice(None)))
-        bb = pl.load(rw_ref, lead + (slice(None), pl.dslice(j * s, s)))
-        bsucc = pl.load(rs_ref, lead + (slice(None), pl.dslice(j * s, s)))
+        a = cw_ref[lead + (pl.ds(i * s, s), slice(None))]
+        asucc = cs_ref[lead + (pl.ds(i * s, s), slice(None))]
+        bb = rw_ref[lead + (slice(None), pl.ds(j * s, s))]
+        bsucc = rs_ref[lead + (slice(None), pl.ds(j * s, s))]
         c = jnp.where(i == b, bb, jnp.where(j == b, a, w_ref[...]))
         cs = jnp.where(i == b, bsucc, jnp.where(j == b, asucc, s_ref[...]))
 
         def body(k, carry):
             t, ts = carry
-            return _relax_succ(k, t, ts, a, asucc, bb)
+            return _relax_succ(k, t, ts, a, asucc, bb, mosaic=True)
 
         c, cs = jax.lax.fori_loop(0, s, body, (c, cs))
         ow_ref[...] = c
@@ -443,6 +445,7 @@ def fw_round(
             f"w must be (n,n) or (B,n,n) with n % {s} == 0, got {w.shape}"
         )
     pltpu = compat.pallas_tpu("fw_round needs pallas TPU scratch + scalar prefetch")
+    compat.check_tpu_lowering(w.dtype, interpret, s)
     T = n // s
     bk = _fit_block(s, bk)
     oi, oj = _round_order(b, T)
@@ -490,7 +493,7 @@ def fw_round(
 def _resolve_bordered_batch_block(
     B: int, rows: int, cols: int, s: int, batch_block: int | None,
     *, word: int, bk: int = 32, variant: str = "fori",
-    vmem_budget: int = 128 << 20,
+    vmem_budget: int | None = None,
 ) -> int:
     """Largest divisor of B whose bordered scratch bands fit VMEM."""
     if batch_block is not None:
@@ -557,6 +560,7 @@ def fw_round_bordered(
     pltpu = compat.pallas_tpu(
         "fw_round_bordered needs pallas TPU scratch + scalar prefetch"
     )
+    compat.check_tpu_lowering(w.dtype, interpret, s)
     tr, tc = rows // s, cols // s
     bk = _fit_block(s, bk)
     oi, oj = _bordered_order(tr, tc)
@@ -638,6 +642,7 @@ def fw_round_with_successors(
     if succ.shape != w.shape:
         raise ValueError(f"succ shape {succ.shape} != w shape {w.shape}")
     pltpu = compat.pallas_tpu("fw_round_with_successors needs pallas TPU scratch")
+    compat.check_tpu_lowering(w.dtype, interpret, s)
     T = n // s
     oi, oj = _round_order(b, T)
     word = jnp.dtype(w.dtype).itemsize + jnp.dtype(succ.dtype).itemsize

@@ -20,14 +20,14 @@ resources a GPU grid actually has:
     them; ``plan.gpu_round_hbm_bytes`` charges their traffic.
   * **full-matrix refs + dynamic tiles** — instead of per-step (s,s) block
     remapping, the kernel sees whole in/out matrices and addresses tile
-    (i·s, j·s) with ``pl.dslice``; the (s,s) tile and the bk-deep band
+    (i·s, j·s) with ``pl.ds``; the (s,s) tile and the bk-deep band
     slices are what Triton stages through shared memory/registers —
     ``plan.gpu_round_smem_bytes`` models that working set against the
     per-SM shared-memory budget the way ``fused_round_vmem_bytes`` models
     VMEM.
 
 Bit-identity: every phase body calls the SAME ``_close_diag`` /
-``_close_row_panel`` / ``_close_col_panel`` / ``_relax_tile`` recurrences as
+``_close_row_panel`` / ``_close_col_panel`` / ``_stage_chunks`` recurrences as
 ``fw_round._round_kernel`` (and the successor round reuses ``_relax_succ``),
 so outputs are bitwise equal to the TPU kernel and the ``kernels/ref.py``
 twins on every semiring × storage lowering, batched and bordered —
@@ -57,10 +57,9 @@ from repro.kernels.fw_round import (
     _close_diag,
     _close_row_panel,
     _relax_succ,
-    _relax_tile,
     _round_order,
 )
-from repro.kernels.minplus_matmul import Variant, _fit_block
+from repro.kernels.minplus_matmul import Variant, _fit_block, _stage_chunks
 
 # Default Triton occupancy hints (overridable per-call; plan.fw_candidates
 # sweeps them for the GPU backend).
@@ -70,7 +69,7 @@ NUM_STAGES = 2
 
 def _tile(lead, i, j, s):
     """Index tuple for the (s,s) tile at tile coordinates (i, j)."""
-    return lead + (pl.dslice(i * s, s), pl.dslice(j * s, s))
+    return lead + (pl.ds(i * s, s), pl.ds(j * s, s))
 
 
 def _round_kernel_gpu(
@@ -96,47 +95,46 @@ def _round_kernel_gpu(
 
     @pl.when(g == 0)
     def _phase1():
-        t = _close_diag(pl.load(w_ref, _tile(lead, i, j, s)), s, semiring)
-        pl.store(o_ref, _tile(lead, i, j, s), t)
+        t = _close_diag(w_ref[_tile(lead, i, j, s)], s, semiring)
+        o_ref[_tile(lead, i, j, s)] = t
         # Seed both bands with the closed diagonal (the TPU kernel's scratch
         # seed): phase-3 steps then read A/B slices unconditionally at any
         # tile index, pivot included.
-        pl.store(row_ref, lead + (slice(None), pl.dslice(j * s, s)), t)
-        pl.store(col_ref, lead + (pl.dslice(i * s, s), slice(None)), t)
+        row_ref[lead + (slice(None), pl.ds(j * s, s))] = t
+        col_ref[lead + (pl.ds(i * s, s), slice(None))] = t
 
     @pl.when((g >= 1) & (g < tc))
     def _phase2_row():
-        d = pl.load(row_ref, lead + (slice(None), pl.dslice(b * s, s)))
-        p = _close_row_panel(pl.load(w_ref, _tile(lead, i, j, s)), d, s, semiring)
+        d = row_ref[lead + (slice(None), pl.ds(b * s, s))]
+        p = _close_row_panel(w_ref[_tile(lead, i, j, s)], d, s, semiring)
         # Owner echo — see fw_round._round_kernel: the border tile at column
         # pc is a broadcast copy of the raw diagonal, whose closed value is
         # the phase-1 closure (≠ the phase-2 recurrence for non-idempotent ⊕).
         p = jnp.where(j == pc, d, p)
-        pl.store(o_ref, _tile(lead, i, j, s), p)
-        pl.store(row_ref, lead + (slice(None), pl.dslice(j * s, s)), p)
+        o_ref[_tile(lead, i, j, s)] = p
+        row_ref[lead + (slice(None), pl.ds(j * s, s))] = p
 
     @pl.when((g >= tc) & (g < tc + tr - 1))
     def _phase2_col():
-        d = pl.load(row_ref, lead + (slice(None), pl.dslice(b * s, s)))
-        p = _close_col_panel(pl.load(w_ref, _tile(lead, i, j, s)), d, s, semiring)
+        d = row_ref[lead + (slice(None), pl.ds(b * s, s))]
+        p = _close_col_panel(w_ref[_tile(lead, i, j, s)], d, s, semiring)
         p = jnp.where(i == pr, d, p)
-        pl.store(o_ref, _tile(lead, i, j, s), p)
-        pl.store(col_ref, lead + (pl.dslice(i * s, s), slice(None)), p)
+        o_ref[_tile(lead, i, j, s)] = p
+        col_ref[lead + (pl.ds(i * s, s), slice(None))] = p
 
     @pl.when(g >= tc + tr - 1)
     def _phase3():
-        a = pl.load(col_ref, lead + (pl.dslice(i * s, s), slice(None)))
-        bb = pl.load(row_ref, lead + (slice(None), pl.dslice(j * s, s)))
+        a = col_ref[lead + (pl.ds(i * s, s), slice(None))]
+        bb = row_ref[lead + (slice(None), pl.ds(j * s, s))]
         # Accumulator input: pivot-band tiles were rewritten this round, so
         # their current value lives in the band buffers, not in w_ref.
         c = jnp.where(
             (i == b) | (i == pr), bb,
             jnp.where((j == b) | (j == pc), a,
-                      pl.load(w_ref, _tile(lead, i, j, s))),
+                      w_ref[_tile(lead, i, j, s)]),
         )
-        pl.store(
-            o_ref, _tile(lead, i, j, s),
-            _relax_tile(c, a, bb, s, bk, semiring, variant),
+        o_ref[_tile(lead, i, j, s)] = _stage_chunks(
+            c, a, bb, bk, semiring, variant
         )
 
 
@@ -167,20 +165,20 @@ def _round_succ_kernel_gpu(
         t, ts = jax.lax.fori_loop(
             0, s,
             body,
-            (pl.load(w_ref, _tile(lead, i, j, s)),
-             pl.load(s_ref, _tile(lead, i, j, s))),
+            (w_ref[_tile(lead, i, j, s)],
+             s_ref[_tile(lead, i, j, s)]),
         )
-        pl.store(ow_ref, _tile(lead, i, j, s), t)
-        pl.store(os_ref, _tile(lead, i, j, s), ts)
-        pl.store(rw_ref, lead + (slice(None), pl.dslice(j * s, s)), t)
-        pl.store(cw_ref, lead + (pl.dslice(i * s, s), slice(None)), t)
-        pl.store(rs_ref, lead + (slice(None), pl.dslice(j * s, s)), ts)
-        pl.store(cs_ref, lead + (pl.dslice(i * s, s), slice(None)), ts)
+        ow_ref[_tile(lead, i, j, s)] = t
+        os_ref[_tile(lead, i, j, s)] = ts
+        rw_ref[lead + (slice(None), pl.ds(j * s, s))] = t
+        cw_ref[lead + (pl.ds(i * s, s), slice(None))] = t
+        rs_ref[lead + (slice(None), pl.ds(j * s, s))] = ts
+        cs_ref[lead + (pl.ds(i * s, s), slice(None))] = ts
 
     @pl.when((g >= 1) & (g < T))
     def _phase2_row():
-        d = pl.load(rw_ref, lead + (slice(None), pl.dslice(b * s, s)))
-        ds = pl.load(rs_ref, lead + (slice(None), pl.dslice(b * s, s)))
+        d = rw_ref[lead + (slice(None), pl.ds(b * s, s))]
+        ds = rs_ref[lead + (slice(None), pl.ds(b * s, s))]
 
         def body(k, c):
             p, ps = c
@@ -189,17 +187,17 @@ def _round_succ_kernel_gpu(
         p, ps = jax.lax.fori_loop(
             0, s,
             body,
-            (pl.load(w_ref, _tile(lead, i, j, s)),
-             pl.load(s_ref, _tile(lead, i, j, s))),
+            (w_ref[_tile(lead, i, j, s)],
+             s_ref[_tile(lead, i, j, s)]),
         )
-        pl.store(ow_ref, _tile(lead, i, j, s), p)
-        pl.store(os_ref, _tile(lead, i, j, s), ps)
-        pl.store(rw_ref, lead + (slice(None), pl.dslice(j * s, s)), p)
-        pl.store(rs_ref, lead + (slice(None), pl.dslice(j * s, s)), ps)
+        ow_ref[_tile(lead, i, j, s)] = p
+        os_ref[_tile(lead, i, j, s)] = ps
+        rw_ref[lead + (slice(None), pl.ds(j * s, s))] = p
+        rs_ref[lead + (slice(None), pl.ds(j * s, s))] = ps
 
     @pl.when((g >= T) & (g < 2 * T - 1))
     def _phase2_col():
-        d = pl.load(rw_ref, lead + (slice(None), pl.dslice(b * s, s)))
+        d = rw_ref[lead + (slice(None), pl.ds(b * s, s))]
 
         def body(k, c):
             p, ps = c
@@ -208,27 +206,27 @@ def _round_succ_kernel_gpu(
         p, ps = jax.lax.fori_loop(
             0, s,
             body,
-            (pl.load(w_ref, _tile(lead, i, j, s)),
-             pl.load(s_ref, _tile(lead, i, j, s))),
+            (w_ref[_tile(lead, i, j, s)],
+             s_ref[_tile(lead, i, j, s)]),
         )
-        pl.store(ow_ref, _tile(lead, i, j, s), p)
-        pl.store(os_ref, _tile(lead, i, j, s), ps)
-        pl.store(cw_ref, lead + (pl.dslice(i * s, s), slice(None)), p)
-        pl.store(cs_ref, lead + (pl.dslice(i * s, s), slice(None)), ps)
+        ow_ref[_tile(lead, i, j, s)] = p
+        os_ref[_tile(lead, i, j, s)] = ps
+        cw_ref[lead + (pl.ds(i * s, s), slice(None))] = p
+        cs_ref[lead + (pl.ds(i * s, s), slice(None))] = ps
 
     @pl.when(g >= 2 * T - 1)
     def _phase3():
-        a = pl.load(cw_ref, lead + (pl.dslice(i * s, s), slice(None)))
-        asucc = pl.load(cs_ref, lead + (pl.dslice(i * s, s), slice(None)))
-        bb = pl.load(rw_ref, lead + (slice(None), pl.dslice(j * s, s)))
-        bsucc = pl.load(rs_ref, lead + (slice(None), pl.dslice(j * s, s)))
+        a = cw_ref[lead + (pl.ds(i * s, s), slice(None))]
+        asucc = cs_ref[lead + (pl.ds(i * s, s), slice(None))]
+        bb = rw_ref[lead + (slice(None), pl.ds(j * s, s))]
+        bsucc = rs_ref[lead + (slice(None), pl.ds(j * s, s))]
         c = jnp.where(
             i == b, bb,
-            jnp.where(j == b, a, pl.load(w_ref, _tile(lead, i, j, s))),
+            jnp.where(j == b, a, w_ref[_tile(lead, i, j, s)]),
         )
         cs = jnp.where(
             i == b, bsucc,
-            jnp.where(j == b, asucc, pl.load(s_ref, _tile(lead, i, j, s))),
+            jnp.where(j == b, asucc, s_ref[_tile(lead, i, j, s)]),
         )
 
         def body(k, carry):
@@ -236,8 +234,8 @@ def _round_succ_kernel_gpu(
             return _relax_succ(k, t, ts, a, asucc, bb)
 
         c, cs = jax.lax.fori_loop(0, s, body, (c, cs))
-        pl.store(ow_ref, _tile(lead, i, j, s), c)
-        pl.store(os_ref, _tile(lead, i, j, s), cs)
+        ow_ref[_tile(lead, i, j, s)] = c
+        os_ref[_tile(lead, i, j, s)] = cs
 
 
 def _gpu_specs(batched, bb, steps, rows, cols, s):
@@ -283,13 +281,6 @@ def _gpu_call(kern, grid, in_specs, out_specs, out_shape, interpret,
               num_warps, num_stages):
     from repro.utils import compat
 
-    kwargs = {}
-    if not interpret:
-        params = compat.gpu_compiler_params(
-            num_warps=num_warps, num_stages=num_stages
-        )
-        if params is not None:
-            kwargs["compiler_params"] = params
     return pl.pallas_call(
         kern,
         grid=grid,
@@ -297,7 +288,9 @@ def _gpu_call(kern, grid, in_specs, out_specs, out_shape, interpret,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
-        **kwargs,
+        compiler_params=None if interpret else compat.gpu_compiler_params(
+            num_warps=num_warps, num_stages=num_stages
+        ),
     )
 
 

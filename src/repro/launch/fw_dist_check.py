@@ -2,8 +2,10 @@
 
 Usage: python -m repro.launch.fw_dist_check [--devices 8] [--n 256] [--bs 32]
 
-Sets XLA_FLAGS *before* importing jax, builds a small host-device mesh, and
-verifies the distributed solve.  Exit code 0 on success.  Modes:
+With ``JAX_PLATFORMS=cpu`` it sets XLA_FLAGS *before* importing jax to get
+``--devices`` virtual host devices; on an accelerator it builds the mesh
+from the real devices.  It then verifies the distributed solve.  Exit code
+0 on success.  Modes:
 
   (default)        fw_distributed == fw_naive (allclose) — the legacy check.
   --bitwise        distributed == the single-device fused solve, BITWISE —
@@ -71,6 +73,68 @@ def _graph_for(semiring: str, n: int, seed: int = 0):
     return random_digraph(n, density=0.3, seed=seed)
 
 
+def bench_metrics(mesh, w, sr, *, bs: int, backend: str = "fused",
+                  row_axes="data", pods: int = 1) -> dict:
+    """Per-round and whole-solve time of the distributed solve of ``w`` on
+    ``mesh``, plus the collective bytes of the compiled per-round program
+    against the SUMMA model.  Runs in the calling process, on whatever
+    devices the mesh holds (``benchmarks.run`` calls it in-process on a
+    chip, through this CLI's subprocess on virtual host devices)."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.apsp import plan
+    from repro.apsp.api import _pad
+    from repro.core.distributed import build_fw_shard_fn
+
+    n = w.shape[-1]
+    ndev = mesh.devices.size
+    R, C = plan.mesh_factorization(ndev, pods)
+    dp = plan.distributed_plan(n, ndev, grid=(R, C), block_size=bs,
+                               pods=pods, word=jnp.dtype(w.dtype).itemsize)
+    s, m = dp["block_size"], dp["n_padded"]
+    wp = _pad(w, m, sr)
+    sharded, sharding = build_fw_shard_fn(
+        mesh, m, block_size=s, row_axes=row_axes, col_axes="model",
+        semiring=sr, backend=backend,
+    )
+    step = jax.jit(sharded)
+    wl = jax.device_put(wp, sharding)
+    # One AOT compile serves both the HLO dump and the timed calls (a
+    # plain step() afterwards would recompile — the jit dispatch cache
+    # is not populated by lower().compile()).
+    compiled = step.lower(wl, jnp.int32(0), jnp.int32(1)).compile()
+    measured = collective_bytes(compiled.as_text())
+    rounds = dp["rounds"]
+    out = compiled(wl, jnp.int32(0), jnp.int32(1))  # warm
+    jax.block_until_ready(out)
+    t0 = time.perf_counter()
+    cur = wl
+    for b in range(rounds):
+        cur = compiled(cur, jnp.int32(b), jnp.int32(1))
+    jax.block_until_ready(cur)
+    round_ms = (time.perf_counter() - t0) / rounds * 1e3
+    # Whole solve measured as ONE jitted all-rounds call (what
+    # fw_distributed/ApspEngine actually dispatch) — not rounds ×
+    # round_ms, which would double-count per-call overhead.
+    full = step.lower(wl, jnp.int32(0), jnp.int32(rounds)).compile()
+    jax.block_until_ready(full(wl, jnp.int32(0), jnp.int32(rounds)))
+    t0 = time.perf_counter()
+    jax.block_until_ready(full(wl, jnp.int32(0), jnp.int32(rounds)))
+    solve_ms = (time.perf_counter() - t0) * 1e3
+    bound_round = dp["summa_bound_bytes"] / rounds
+    return dict(
+        ndev=ndev, R=R, C=C, n=n, n_padded=m, bs=s,
+        backend=backend, rounds=rounds, round_ms=round_ms,
+        solve_ms=solve_ms,
+        comm_measured_bytes=measured,
+        comm_model_bytes=dp["comm_bytes_per_round"],
+        summa_bound_bytes_per_round=bound_round,
+        comm_efficiency_measured=(bound_round / measured) if measured else None,
+        comm_efficiency_model=dp["comm_model_efficiency"],
+    )
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--devices", type=int, default=8)
@@ -103,10 +167,14 @@ def main() -> int:
     ap.add_argument("--phase2-shard", action="store_true")
     args = ap.parse_args()
 
-    os.environ["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={args.devices} "
-        + os.environ.get("XLA_FLAGS", "")
-    )
+    # CPU runs get --devices virtual host devices; on an accelerator the
+    # mesh is built from the real devices and --devices is ignored.
+    host = os.environ.get("JAX_PLATFORMS") == "cpu"
+    if host:
+        os.environ["XLA_FLAGS"] = (
+            f"--xla_force_host_platform_device_count={args.devices} "
+            + os.environ.get("XLA_FLAGS", "")
+        )
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -116,9 +184,13 @@ def main() -> int:
     from repro.core.distributed import build_fw_shard_fn, fw_distributed
     from repro.core.semiring import SEMIRINGS
     from repro.launch.mesh import make_host_mesh
+    from repro.utils.compat import enable_compile_cache
 
+    enable_compile_cache()
     ndev = len(jax.devices())
-    assert ndev == args.devices, (ndev, args.devices)
+    if host:
+        assert ndev == args.devices, (ndev, args.devices)
+    args.devices = ndev
     # make_host_mesh builds from apsp.plan.mesh_factorization — the same
     # (R, C) grid benchmarks use to derive the SUMMA comm bound.
     mesh = make_host_mesh(args.devices, pods=args.pods)
@@ -285,54 +357,13 @@ def main() -> int:
         return 0
 
     if args.bench:
-        dp = plan.distributed_plan(args.n, args.devices, grid=(R, C),
-                                   block_size=args.bs, pods=args.pods,
-                                   word=dtype.itemsize)
-        s, m = dp["block_size"], dp["n_padded"]
-        from repro.apsp.api import _pad
-
-        wp = _pad(w, m, sr)
-        sharded, sharding = build_fw_shard_fn(
-            mesh, m, block_size=s, row_axes=row_axes, col_axes="model",
-            semiring=sr, backend=args.backend,
-        )
-        step = jax.jit(sharded)
-        wl = jax.device_put(wp, sharding)
-        # One AOT compile serves both the HLO dump and the timed calls (a
-        # plain step() afterwards would recompile — the jit dispatch cache
-        # is not populated by lower().compile()).
-        compiled = step.lower(wl, jnp.int32(0), jnp.int32(1)).compile()
-        measured = collective_bytes(compiled.as_text())
-        rounds = dp["rounds"]
-        out = compiled(wl, jnp.int32(0), jnp.int32(1))  # warm
-        jax.block_until_ready(out)
-        t0 = time.perf_counter()
-        cur = wl
-        for b in range(rounds):
-            cur = compiled(cur, jnp.int32(b), jnp.int32(1))
-        jax.block_until_ready(cur)
-        round_ms = (time.perf_counter() - t0) / rounds * 1e3
-        # Whole solve measured as ONE jitted all-rounds call (what
-        # fw_distributed/ApspEngine actually dispatch) — not rounds ×
-        # round_ms, which would double-count per-call overhead.
-        full = step.lower(wl, jnp.int32(0), jnp.int32(rounds)).compile()
-        jax.block_until_ready(full(wl, jnp.int32(0), jnp.int32(rounds)))
-        t0 = time.perf_counter()
-        jax.block_until_ready(full(wl, jnp.int32(0), jnp.int32(rounds)))
-        solve_ms = (time.perf_counter() - t0) * 1e3
-        bound_round = dp["summa_bound_bytes"] / rounds
-        metrics = dict(
-            ndev=ndev, R=R, C=C, n=args.n, n_padded=m, bs=s,
-            backend=args.backend, rounds=rounds, round_ms=round_ms,
-            solve_ms=solve_ms,
-            comm_measured_bytes=measured,
-            comm_model_bytes=dp["comm_bytes_per_round"],
-            summa_bound_bytes_per_round=bound_round,
-            comm_efficiency_measured=(bound_round / measured) if measured else None,
-            comm_efficiency_model=dp["comm_model_efficiency"],
+        metrics = bench_metrics(
+            mesh, w, sr, bs=args.bs, backend=args.backend,
+            row_axes=row_axes, pods=args.pods,
         )
         print("METRICS " + json.dumps(metrics))
-        print(f"OK bench ndev={ndev} n={args.n} bs={s} backend={args.backend}")
+        print(f"OK bench ndev={ndev} n={args.n} bs={metrics['bs']} "
+              f"backend={args.backend}")
         return 0
 
     if args.method == "engine":

@@ -136,6 +136,9 @@ def run(n: int, block_size: int, multi_pod: bool, backend: str,
 
 
 def main():
+    from repro.utils.compat import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=65536)
     ap.add_argument("--block-size", type=int, default=128)

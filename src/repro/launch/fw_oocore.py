@@ -151,6 +151,9 @@ def smoke() -> int:
 
 
 def main() -> int:
+    from repro.utils.compat import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=1024)
     ap.add_argument("--budget", type=int, default=None,

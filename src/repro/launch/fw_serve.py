@@ -454,6 +454,9 @@ def run_load(
 
 
 def main() -> int:
+    from repro.utils.compat import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--graphs", type=int, default=8)
     ap.add_argument("--n", type=int, default=256)

@@ -7,17 +7,13 @@ jax initialization.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from repro.apsp.plan import mesh_factorization
 
 
 def _make_mesh(shape, axes):
-    try:  # axis_types only exists on newer jax
-        from jax.sharding import AxisType
-
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    except ImportError:
-        return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -28,7 +24,8 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_host_mesh(n_devices: int | None = None, *, pods: int = 1):
-    """Small CPU-device mesh for tests/examples (devices already forced).
+    """Mesh over the first ``n_devices`` devices (all by default): forced
+    host devices in tests/examples, the real chips on an accelerator.
 
     Uses the same (R, C) factorization as launch.fw_dist_check
     (repro.apsp.plan.mesh_factorization).

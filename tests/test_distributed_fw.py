@@ -16,6 +16,7 @@ def run_check(*args):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"  # virtual host devices
     res = subprocess.run(
         [sys.executable, "-m", "repro.launch.fw_dist_check", *args],
         capture_output=True, text=True, timeout=600, env=env, cwd=REPO,

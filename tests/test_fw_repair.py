@@ -234,6 +234,7 @@ def _run_dist_repair(*args):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"  # virtual host devices
     res = subprocess.run(
         [sys.executable, "-m", "repro.launch.fw_dist_check",
          "--devices", "8", "--n", "64", "--repair", *args],

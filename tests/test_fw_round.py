@@ -350,13 +350,15 @@ def test_phase2_fits_block_to_any_n():
 
 # ------------------------------------------------------------ plan-layer model
 def test_plan_fused_model():
-    # scratch bands dominate: 2·s·n + 2·2·s² words.
+    # scratch bands dominate: 2·s·n + 2·2·s² words, plus the body's live
+    # (s,s) tiles on Mosaic's stack.
+    live = plan.LIVE_TILES * 128 * 128
     assert plan.fused_round_vmem_bytes(1024, 128, 32) == (
-        (2 * 128 * 1024 + 4 * 128 * 128) * 4
+        (2 * 128 * 1024 + 4 * 128 * 128 + live) * 4
     )
     # broadcast variant adds the (s, bk, s) product transient.
     assert plan.fused_round_vmem_bytes(1024, 128, 32, variant="broadcast") == (
-        (2 * 128 * 1024 + 4 * 128 * 128 + 128 * 32 * 128) * 4
+        (2 * 128 * 1024 + 4 * 128 * 128 + live + 128 * 32 * 128) * 4
     )
     assert plan.fused_round_steps(1024, 128) == 8 * 8 + 2 * 8 - 1
     # one read + one write per grid step, (s,s) words each.
