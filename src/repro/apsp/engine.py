@@ -277,7 +277,14 @@ class ApspEngine:
         dist_plan = None
         rec_plan = None
         if s is not None:
-            bk = min(bk, s)
+            if meth in ("staged", "fused", "distributed"):
+                # The depth the round kernel runs: bk = s for the TPU
+                # "fori" kernels, which do not read bk.
+                bk = plan.kernel_bk(
+                    s, bk, variant=self.variant, backend=self._backend
+                )
+            else:
+                bk = min(bk, s)
             if meth == "recursive":
                 # Planned ONCE here; _build consumes the same dict, so the
                 # key's (leaf, oocore) and the executor's schedule cannot
@@ -287,12 +294,16 @@ class ApspEngine:
                     block_size=s, batch=batch, dtype=dtype, bk=bk,
                     variant=self.variant,
                 )
-            elif meth in ("staged", "fused"):
+            elif meth in ("staged", "fused") and self._backend == "tpu":
                 bb = self.batch_block or plan.auto_batch_block(
                     batch, m, s, bk=bk, variant=self.variant,
                     word=jnp.dtype(dtype).itemsize,
                     vmem_budget=self.vmem_budget, successors=successors,
                 )
+            elif meth in ("staged", "fused"):
+                # The Triton round and the XLA twin keep no VMEM scratch:
+                # the whole batch is one block.
+                bb = self.batch_block or batch
             elif meth == "distributed":
                 from repro.core.distributed import _axis_size
 

@@ -55,19 +55,16 @@ def round_count(n: int, block_size: int) -> int:
     return padded_size(n, block_size) // block_size
 
 
-def auto_block_size(
-    n: int, *, max_block: int = 128, min_block: int | None = None
-) -> int:
+def auto_block_size(n: int, *, max_block: int = 128) -> int:
     """Pick a pivot-tile size for an n-vertex graph.
 
     128 (the paper's sweet spot on our VMEM budget) once n is large enough;
     below that, the largest power of two <= ~n/4 so padding waste stays
     bounded (< 33%) while phase 1 still amortizes — but never below
-    ``min_block`` (None → ``compat.min_block_size()``: 128 on TPU, where
-    Mosaic tiles blocks by lanes and small graphs pad up; 16 elsewhere).
+    ``compat.min_block_size()``: 128 on TPU, where Mosaic tiles blocks by
+    lanes and small graphs pad up; 16 elsewhere.
     """
-    if min_block is None:
-        min_block = _min_block()
+    min_block = _min_block()
     if n >= max_block * 2:
         return max(max_block, min_block)
     s = 1 << max(4, (max(n, 2) - 1).bit_length() - 2)
@@ -81,12 +78,36 @@ def _min_block() -> int:
 
 
 def _vmem_budget(vmem_budget: int | None) -> int:
-    """An explicit budget, else the device's (``compat.vmem_limit_bytes``)."""
+    """An explicit budget, else the device's (``compat.vmem_limit_bytes``).
+    Only TPU planning calls this: the other lowerings keep no VMEM scratch."""
     if vmem_budget is not None:
         return vmem_budget
     from repro.utils import compat
 
     return compat.vmem_limit_bytes()
+
+
+def kernel_bk(
+    s: int, bk: int, *, variant: str = "fori", backend: str = "tpu"
+) -> int:
+    """The phase-3 staging depth a round runs when ``bk`` is asked for.
+
+    The Pallas TPU kernels' "fori" variant relaxes a tile over all s
+    rank-1 steps in one loop, one rotate per
+    ``minplus_matmul._PICK_GROUP`` steps, whatever ``bk`` says: its depth
+    is s.  The "unroll" and "broadcast" variants, the Triton round and the
+    XLA twins stage by ``bk`` (at most s).
+    """
+    if backend == "tpu" and variant == "fori":
+        return s
+    return min(bk, s)
+
+
+def grid_k_step(k: int, bk: int) -> int:
+    """``semiring_matmul``'s contraction step per grid step: the largest
+    multiple of ``bk`` that divides ``k``, up to one lane tile (128) — an
+    (m, kb) A block Mosaic accepts."""
+    return max(c for c in range(bk, min(k, max(bk, 128)) + 1, bk) if k % c == 0)
 
 
 def mesh_factorization(devices: int, pods: int = 1) -> tuple[int, int]:
@@ -543,7 +564,11 @@ def fw_candidates(
     candidate is stamped with it):
 
       * ``"tpu"`` — the historical set: fused (VMEM scratch model), staged,
-        and recursive candidates against ``vmem_budget``.
+        and recursive candidates against ``vmem_budget``.  Each tile
+        carries the distinct depths ``kernel_bk`` maps ``bks`` to: one,
+        bk = s, for the "fori" variant, which does not read bk; staged
+        candidates count and charge the ``grid_k_step`` slices the
+        contraction kernel streams.
       * ``"gpu"`` — fused candidates ONLY (the Triton round is the one GPU
         lowering), filtered by ``gpu_round_smem_bytes`` against
         ``smem_budget`` with an ``occupancy`` field (blocks co-resident per
@@ -558,7 +583,6 @@ def fw_candidates(
     """
     if word is None:
         word = word_for(dtype)
-    vmem_budget = _vmem_budget(vmem_budget)
     if lanes < 1:
         raise ValueError(f"lanes must be >= 1, got {lanes}")
     if backend not in ("tpu", "gpu", "ref"):
@@ -617,6 +641,7 @@ def fw_candidates(
                     **extra,
                 ))
         return out
+    vmem_budget = _vmem_budget(vmem_budget)
     for s in block_sizes:
         if s > max(n, 16):
             continue
@@ -628,10 +653,12 @@ def fw_candidates(
             # The HBM-resident lowerings need the whole padded matrix on
             # device; past the budget only the recursive stream qualifies.
             continue
-        for bk in bks:
-            if bk > sp:
-                continue
+        depths = dict.fromkeys(
+            kernel_bk(sp, bk, variant=variant) for bk in bks if bk <= sp
+        )
+        for bk in depths:
             rounds = m // sp
+            kb = grid_k_step(sp, bk)
             bb = auto_batch_block(
                 batch, m, sp, bk=bk, word=word, variant=variant,
                 vmem_budget=vmem_budget,
@@ -658,7 +685,7 @@ def fw_candidates(
             for bm in (sp, 2 * sp):
                 if bm > m:
                     continue
-                v3 = phase3_vmem_bytes(bm, bm, bk, word=word, fused=True)
+                v3 = phase3_vmem_bytes(bm, bm, kb, word=word, fused=True)
                 if v3 <= vmem_budget:
                     per_round = batch * staged_hbm_bytes_per_round(
                         m, m, sp, bm=bm, bn=bm, word=word
@@ -674,7 +701,7 @@ def fw_candidates(
                         / (batch * lanes),
                         pcie_bytes_total=0.0,
                         total_bytes=rounds * per_round,
-                        steps_per_round=batch * (m // bm) ** 2 * (sp // bk),
+                        steps_per_round=batch * (m // bm) ** 2 * (sp // kb),
                         dispatches_per_round=4,
                     ))
     if include_recursive:
@@ -756,15 +783,14 @@ def autotune_fw(
     backend (TPU VMEM vs GPU SMEM byte models are not commensurable), and
     the stamp is the per-key provenance the benchmarks persist.
     """
-    vmem_budget = _vmem_budget(vmem_budget)
     cands = fw_candidates(n, backend=backend, batch=batch,
                           vmem_budget=vmem_budget, smem_budget=smem_budget,
                           dtype=dtype, lanes=lanes, variant=variant,
                           hbm_budget=hbm_budget)
     if not cands:
         raise ValueError(
-            f"no viable round config for n={n} within vmem_budget="
-            f"{vmem_budget}; pass smaller block_sizes via fw_candidates"
+            f"no viable {backend} round config for n={n} within its "
+            f"on-chip budget; pass smaller block_sizes via fw_candidates"
         )
     if measure is not None:
         for c in cands:

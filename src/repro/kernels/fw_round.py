@@ -429,7 +429,9 @@ def fw_round(
     round of B graphs through one dispatch (leading batch grid dimension);
     b: pivot round index (may be traced — it only feeds the scalar-prefetch
     order arrays, never a shape).
-    bk: phase-3 staging depth (clamped to a divisor of block_size).
+    bk: phase-3 staging depth of the "unroll" and "broadcast" variants
+    (clamped to a divisor of block_size); "fori" runs all block_size steps
+    in one loop and does not read it (``plan.kernel_bk``).
     batch_block: graphs per grid step in the batched case (must divide B;
     None → largest divisor whose scratch bands fit the VMEM budget).
     """

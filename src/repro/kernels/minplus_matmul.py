@@ -54,8 +54,9 @@ Variant = Literal["fori", "unroll", "broadcast"]
 # is exact (bf16 ⊂ f32, int16 ⊂ int32).
 _WIDE = {jnp.dtype(jnp.bfloat16): jnp.float32, jnp.dtype(jnp.int16): jnp.int32}
 
-# Rank-1 steps per rotate in the kernels' phase-3 loop: a rotate per step
-# ran the n=4096 f32 fused solve 4.3x slower than static picks on a v5e.
+# Rank-1 steps per rotate in the kernels' phase-3 loop.  With a rotate per
+# step, the rotates and not the rank-1 arithmetic bounded phase 3 on a v5e
+# (host-clock solve times only; no kernel trace yet).
 _PICK_GROUP = 8
 
 
@@ -262,8 +263,9 @@ def semiring_matmul(
     bm, bn, bk = _fit_block(m, bm), _fit_block(n, bn), _fit_block(k, bk)
     if m % bm or n % bn or k % bk:
         raise ValueError(f"shape ({m},{k})x({k2},{n}) not divisible by ({bm},{bn},{bk})")
-    # Grid k step: the largest multiple of bk dividing k, up to one lane tile.
-    kb = max(c for c in range(bk, min(k, max(bk, 128)) + 1, bk) if k % c == 0)
+    from repro.apsp import plan  # call-time import: apsp imports this module
+
+    kb = plan.grid_k_step(k, bk)
     if B is None:
         grid = (m // bm, n // bn, k // kb)
         a_spec = pl.BlockSpec((bm, kb), lambda i, j, kk: (i, kk))

@@ -32,10 +32,11 @@ _GPU_PLATFORMS = ("gpu", "cuda", "rocm")
 # has 128 MiB of VMEM, but Mosaic grants a kernel 16 MiB unless asked: the
 # fused round's (s, n) + (n, s) scratch bands outgrow that past n = 8192 in
 # f32.  The headroom under 128 MiB is left to Mosaic's internal scratch.
-# CPU runs interpret the TPU kernels, so they plan as for the v5e they
-# rehearse.  A device kind missing here is an error, never a default.
+# A TPU device kind missing here is an error, never a default.
 _VMEM_LIMIT_BYTES = {"TPU v5 lite": 100 << 20}
-_VMEM_LIMIT_BYTES["cpu"] = _VMEM_LIMIT_BYTES["TPU v5 lite"]
+# Off a TPU the TPU kernels only run interpreted, as rehearsals of this
+# device kind, so they plan as for it.
+_REHEARSED_KIND = "TPU v5 lite"
 
 # Mosaic tiles the last two block dims by (8, 128): on TPU every (s, s)
 # block is a lane-tile multiple and smaller graphs pad up to it.
@@ -98,8 +99,11 @@ def pallas_tpu(need: str = "pallas TPU scratch + scalar prefetch") -> Any:
 
 def vmem_limit_bytes() -> int:
     """Scoped-VMEM budget of ``jax.devices()[0]`` — the limit every TPU
-    kernel compiles with and the budget ``apsp.plan`` fits scratch into."""
-    kind = jax.devices()[0].device_kind
+    kernel compiles with and the budget ``apsp.plan`` fits TPU scratch
+    into.  Only the TPU lowerings read it; the Triton round and the XLA
+    twins keep no VMEM scratch."""
+    dev = jax.devices()[0]
+    kind = dev.device_kind if dev.platform == "tpu" else _REHEARSED_KIND
     try:
         return _VMEM_LIMIT_BYTES[kind]
     except KeyError:

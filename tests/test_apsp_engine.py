@@ -130,6 +130,17 @@ def test_plan_for_models_fused_round():
     assert eng.plan_for(100, batch=16) is entry
 
 
+@pytest.mark.parametrize(
+    "backend,variant,want_bk",
+    [("tpu", "fori", 32), ("tpu", "unroll", 16), ("ref", "fori", 16)],
+)
+def test_plan_key_carries_the_depth_the_kernel_runs(backend, variant, want_bk):
+    # The TPU "fori" round does not read bk, so its key pins bk = s.
+    eng = ApspEngine(method="fused", block_size=32, bk=16, backend=backend,
+                     variant=variant, validate=False)
+    assert eng.plan_for(100, batch=2).key.bk == want_bk
+
+
 def test_bucketing_counts_and_order():
     eng = ApspEngine(method="fused", block_size=32, validate=False)
     sizes = (90, 40, 96, 40, 20)

@@ -398,6 +398,34 @@ def test_plan_batch_models():
         assert c["hbm_bytes_per_round"] == 8 * b["hbm_bytes_per_round"]
 
 
+def test_plan_staging_depth_follows_the_kernels():
+    # The TPU "fori" kernels do not read bk: one candidate per tile, bk = s.
+    assert plan.kernel_bk(128, 32) == 128
+    assert plan.kernel_bk(128, 32, variant="unroll") == 32
+    assert plan.kernel_bk(128, 256, variant="broadcast") == 128
+    assert plan.kernel_bk(128, 32, backend="ref") == 32
+    kw = dict(block_sizes=(64, 128), bks=(8, 16, 32, 64, 128))
+    fori = plan.fw_candidates(512, **kw)
+    assert {(c["impl"], c["block_size"], c["bk"]) for c in fori} == {
+        (impl, s, s) for impl in ("fused", "staged") for s in (64, 128)
+    }
+    unroll = plan.fw_candidates(512, variant="unroll", **kw)
+    assert {c["bk"] for c in unroll if c["block_size"] == 128} == {
+        8, 16, 32, 64, 128
+    }
+    # Staged candidates count and charge the slices semiring_matmul streams.
+    assert plan.grid_k_step(128, 16) == 128
+    assert plan.grid_k_step(256, 256) == 256
+    assert plan.grid_k_step(96, 32) == 96
+    for c in unroll:
+        if c["impl"] == "staged":
+            kb = plan.grid_k_step(c["block_size"], c["bk"])
+            assert c["steps_per_round"] == (512 // c["bm"]) ** 2 * (
+                c["block_size"] // kb)
+            assert c["vmem_bytes"] == plan.phase3_vmem_bytes(
+                c["bm"], c["bm"], kb, fused=True)
+
+
 def test_plan_candidates_and_autotune():
     cands = plan.fw_candidates(1024)
     assert cands and all(c["vmem_bytes"] <= 128 << 20 for c in cands)

@@ -365,6 +365,46 @@ def test_fw_candidates_per_backend_sets():
         plan.fw_candidates(256, backend="cuda")
 
 
+def test_gpu_and_ref_planning_skip_the_vmem_table(monkeypatch):
+    """The TPU VMEM table has no GPU device kinds: the gpu and ref pools,
+    their ranking and the engine's gpu/ref plans must never read it."""
+    def no_table():
+        raise AssertionError("gpu/ref planning read the TPU VMEM table")
+
+    monkeypatch.setattr(compat, "vmem_limit_bytes", no_table)
+    for be in ("gpu", "ref"):
+        assert plan.fw_candidates(256, backend=be, batch=4)
+        assert plan.autotune_fw(256, backend=be, batch=4)
+        eng = ApspEngine(method="fused", block_size=32, backend=be,
+                         validate=False)
+        entry = eng.plan_for(100, batch=4)
+        assert entry.key.backend == be and entry.key.batch_block == 4
+    with pytest.raises(AssertionError, match="VMEM table"):
+        plan.fw_candidates(256, backend="tpu")
+
+
+class _Device:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+@pytest.mark.parametrize(
+    "platform,kind,want",
+    [("tpu", "TPU v5 lite", 100 << 20), ("gpu", "NVIDIA H100", 100 << 20),
+     ("cpu", "cpu", 100 << 20), ("tpu", "TPU v9 unknown", None)],
+    ids=["v5e", "gpu_host", "cpu_host", "unknown_tpu"],
+)
+def test_vmem_budget_by_device_kind(monkeypatch, platform, kind, want):
+    # Off a TPU the TPU kernels only run interpreted, as v5e rehearsals;
+    # a TPU kind missing from the table is an error, never a default.
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Device(platform, kind)])
+    if want is None:
+        with pytest.raises(ValueError, match="no VMEM budget"):
+            compat.vmem_limit_bytes()
+    else:
+        assert compat.vmem_limit_bytes() == want
+
+
 def test_gpu_byte_models():
     # SMEM: 2s² tile copies + 2(s·bk + bk·s) staged slices, in words.
     assert plan.gpu_round_smem_bytes(32, 16, word=4) == \
