@@ -1,7 +1,7 @@
 """Planning arithmetic for APSP solves — one home for the numbers.
 
 Everything here is host-side integer/float arithmetic shared by the solver
-front-end (``repro.apsp.solve``), the benchmarks, and the launch tooling,
+front-end (``repro.apsp.solve``), the engine, and the launch tooling,
 so block-size selection, padding, mesh factorization, and the roofline
 byte models cannot drift between callers.  The formulas are documented in
 EXPERIMENTS.md (§Roofline, §Perf).
@@ -115,9 +115,9 @@ def mesh_factorization(devices: int, pods: int = 1) -> tuple[int, int]:
 
     R = product of the row axes (pod × data), C = the model axis.  Single
     source of truth: ``launch.mesh.make_host_mesh`` builds meshes from it
-    (fw_dist_check runs on those) and benchmarks derive their SUMMA comm
-    bound from it, so the reported comm efficiency always matches the mesh
-    the check actually ran on.
+    (fw_dist_check runs on those) and derives its SUMMA comm bound from
+    it, so the reported comm efficiency always matches the mesh the check
+    actually ran on.
     """
     if pods > 1:
         rows = max(1, devices // pods // 2)
@@ -149,8 +149,8 @@ def dist_round_comm_bytes(
     their mesh axes.  Summed over the n/s rounds this exceeds the SUMMA
     bound (``summa_comm_bound_bytes``) by exactly the redundant diagonal
     term — the model side of the measured-vs-model comm-efficiency number
-    ``benchmarks.run`` records (the measured side comes from the collective
-    ops in the compiled HLO; see launch/fw_dist_check --bench).
+    (the measured side comes from the collective ops in the compiled HLO;
+    see launch/fw_dist_check --bench).
     """
     return batch * (s * s + s * (n // C) + (n // R) * s) * word
 
@@ -385,8 +385,8 @@ def fused_solve_hbm_bytes(
     n: int, s: int, *, word: int = 4, batch: int = 1
 ) -> float:
     """Modeled HBM traffic of a WHOLE fused solve: n/s rounds ×
-    ``fused_round_hbm_bytes`` — the numerator of the achieved-bandwidth
-    number the benchmarks report."""
+    ``fused_round_hbm_bytes`` — the numerator of an achieved-bandwidth
+    figure."""
     return round_count(n, s) * fused_round_hbm_bytes(
         n, s, word=word, batch=batch
     )
@@ -405,8 +405,9 @@ def repair_hbm_bytes(
     The repair-vs-resolve crossover the serving policy uses
     (``ApspEngine.should_repair``): this is ~2·(E+T)·s·n words against
     ``fused_solve_hbm_bytes``'s ~2·(n/s)·(T²+2T-1)·s² — repair wins by
-    roughly a factor of n/s per small edge batch, which is also the
-    measured ``fw_repair/speedup`` ladder in BENCH_fw.json.
+    roughly a factor of n/s per small edge batch, which is also what the
+    ``fw_repair/speedup`` ladder showed (388× at n=1024, a CPU-container
+    figure, not a chip measurement).
     """
     m = padded_size(n, s)
     bands = edges + m // s
@@ -781,7 +782,7 @@ def autotune_fw(
     ``backend`` resolves the candidate pool (``fw_candidates(backend=)``)
     and every returned dict is stamped with it — ranking happens WITHIN a
     backend (TPU VMEM vs GPU SMEM byte models are not commensurable), and
-    the stamp is the per-key provenance the benchmarks persist.
+    the stamp says which model ranked it.
     """
     cands = fw_candidates(n, backend=backend, batch=batch,
                           vmem_budget=vmem_budget, smem_budget=smem_budget,

@@ -276,6 +276,7 @@ def fw_repair(
     kern = functools.partial(_repair_kernel, n=n, s=s, E=E, semiring=semiring)
     return pl.pallas_call(
         kern,
+        name="fw_repair",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(d.shape, d.dtype),
         interpret=interpret,
@@ -334,6 +335,7 @@ def fw_repair_with_successors(
     kern = functools.partial(_repair_succ_kernel, n=n, s=s, E=E)
     return pl.pallas_call(
         kern,
+        name="fw_repair_with_successors",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct(d.shape, d.dtype),

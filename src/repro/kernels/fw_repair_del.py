@@ -441,6 +441,7 @@ def _sweep_round(
     )
     return pl.pallas_call(
         kern,
+        name="fw_repair_del_sweep",
         grid_spec=grid_spec,
         out_shape=(
             jax.ShapeDtypeStruct(band.shape, band.dtype),

@@ -84,6 +84,12 @@ bands — so the distributed solve is bitwise equal to the single-device
 fused solve for every semiring, including non-idempotent ⊕ (plus_mul),
 where a re-relaxed band would otherwise double-count
 (tests/test_distributed.py).  See docs/KERNELS.md §Distributed round.
+
+Each ``pallas_call`` passes ``name=``, which becomes its HLO op name in the
+compiled program and in a device trace (``%fw_round.<i>``,
+``%fw_round_with_successors.<i>``, ``%fw_round_bordered.<i>``), whatever
+jit wraps it; the benchmark's kernel metrics match these names and
+tests/test_tpu_compile.py pins them.
 """
 from __future__ import annotations
 
@@ -483,6 +489,7 @@ def fw_round(
     )
     return pl.pallas_call(
         kern,
+        name="fw_round",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(w.shape, w.dtype),
         interpret=interpret,
@@ -601,6 +608,7 @@ def fw_round_bordered(
     )
     return pl.pallas_call(
         kern,
+        name="fw_round_bordered",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(w.shape, w.dtype),
         interpret=interpret,
@@ -685,6 +693,7 @@ def fw_round_with_successors(
     )
     return pl.pallas_call(
         kern,
+        name="fw_round_with_successors",
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,

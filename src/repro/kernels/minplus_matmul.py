@@ -209,6 +209,7 @@ def _grid_call(kernel, out_shape, grid, in_specs, out_specs, interpret, *args):
     )
     return pl.pallas_call(
         kernel,
+        name="semiring_matmul",
         out_shape=out_shape,
         grid=grid,
         in_specs=in_specs,

@@ -27,7 +27,7 @@ from the real devices.  It then verifies the distributed solve.  Exit code
                    bytes in the compiled per-round HLO against the SUMMA
                    model (plan.dist_round_comm_bytes /
                    plan.summa_comm_bound_bytes); prints a ``METRICS {json}``
-                   line benchmarks.run parses into BENCH_fw.json.
+                   line.
 
 tests/test_distributed.py drives the bitwise matrix (5 semirings × 2
 dtypes); .github/workflows/ci.yml runs the 8-virtual-device smoke.
@@ -78,8 +78,7 @@ def bench_metrics(mesh, w, sr, *, bs: int, backend: str = "fused",
     """Per-round and whole-solve time of the distributed solve of ``w`` on
     ``mesh``, plus the collective bytes of the compiled per-round program
     against the SUMMA model.  Runs in the calling process, on whatever
-    devices the mesh holds (``benchmarks.run`` calls it in-process on a
-    chip, through this CLI's subprocess on virtual host devices)."""
+    devices the mesh holds."""
     import jax
     import jax.numpy as jnp
 

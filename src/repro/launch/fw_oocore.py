@@ -8,8 +8,8 @@ Default mode runs one capped-``hbm_budget`` streamed solve (panels host →
 device through ``apsp.kleene.HostPanelStore``) plus the in-core fused
 baseline at the same padded shape, checks them bitwise, compares measured
 h2d/d2h stream bytes against the ``plan.recursive_plan`` transfer model,
-and prints a ``METRICS {json}`` line ``benchmarks.run`` folds into the
-``fw_oocore/*`` ladder of BENCH_fw.json.
+and prints a ``METRICS {json}`` line of them (host wall times; the
+benchmark on the chip is ``bench/run.py``).
 
 ``--smoke`` is the CI guard (.github/workflows/ci.yml oocore-smoke), the
 ISSUE 8 acceptance run:

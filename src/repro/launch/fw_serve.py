@@ -9,8 +9,9 @@ Default mode drives a mixed query/update load through ``serve.routing
 micro-batching scheduler), an ⊕-improving ``update_edge`` every
 ``--update-every`` queries so refreshes alternate between the rank-1 repair
 fast path and full re-solves.  Reports per-query p50/p99 latency and QPS,
-and prints a ``METRICS {json}`` line ``benchmarks.run`` parses into
-``BENCH_fw.json`` (the ``serve_qps/*`` ladder).
+and prints a ``METRICS {json}`` line of them.  These are host wall times
+of a closed loop; the benchmark on the chip is ``bench/run.py``
+(``BENCHMARK.json``).
 
 ``--smoke`` is the CI guard (.github/workflows/ci.yml serve-smoke):
 
@@ -23,15 +24,12 @@ and prints a ``METRICS {json}`` line ``benchmarks.run`` parses into
   * successor-table repair == re-solve on tie-free weights;
   * snapshot consistency mid-refresh (a reader's snapshot is immutable
     across a racing publish);
-  * a mini load-gen pass through the scheduler;
-  * BENCH_fw.json key-manifest diff for the ``serve_qps/*`` +
-    ``fw_repair/*`` + ``fw_repair_del/*`` ladders.
+  * a mini load-gen pass through the scheduler.
 """
 from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 import time
 
@@ -357,32 +355,6 @@ def smoke() -> int:
               file=sys.stderr)
         return 1
     print("smoke: fail_link → repair_del refresh == re-solve (dist AND succ)")
-
-    # 6) BENCH_fw.json manifest diff for the serving ladders.
-    repo = os.path.dirname(
-        os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__)))))
-    bench = os.path.join(repo, "BENCH_fw.json")
-    if not os.path.exists(bench):
-        print(f"FAIL {bench} missing — run the benchmarks first",
-              file=sys.stderr)
-        return 1
-    sys.path.insert(0, repo)
-    from benchmarks.run import expected_keys
-
-    with open(bench) as f:
-        have = set(json.load(f))
-    want = (
-        set(expected_keys()["fw_repair"])
-        | set(expected_keys()["fw_repair_del"])
-        | set(expected_keys()["serve_qps"])
-    )
-    missing = sorted(want - have)
-    for k in missing:
-        print(f"FAIL missing benchmark entry {k!r}", file=sys.stderr)
-    if missing:
-        return 1
-    print(f"smoke: BENCH_fw.json has all {len(want)} serving-ladder keys")
     return 0
 
 

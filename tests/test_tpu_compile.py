@@ -16,6 +16,7 @@ an entry written for a described chip cannot be read back without one.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -208,3 +209,154 @@ def test_bordered_round_compiles_on_4_chip_mesh(topo):
     )
     assert "tpu_custom_call" in text
     assert "all-reduce" in text
+
+
+# ------------------------------------------------------------- trace names
+# The benchmark reads the device trace by name: the solve program's module
+# (``jit_apsp_solve``), the round kernels' HLO ops (``%fw_round.<i>``,
+# ``%fw_round_with_successors.<i>``) and the per-round ``copy`` ops inside
+# the solve's ``while`` loop.  A rename here would silently null a metric.
+SMALL = 2048
+
+
+def _computations(text: str) -> dict[str, list[str]]:
+    """Compiled HLO text -> {computation name: its instruction lines};
+    the entry computation is also under "ENTRY"."""
+    comps: dict[str, list[str]] = {}
+    body = None
+    for line in text.splitlines():
+        if body is None and line.endswith("{") and not line[:1].isspace():
+            head = line.split()
+            entry = head[0] == "ENTRY"
+            name = (head[1] if entry else head[0]).lstrip("%")
+            if name == "HloModule":
+                continue
+            body = comps.setdefault(name, [])
+            if entry:
+                comps["ENTRY"] = body
+        elif body is not None and line == "}":
+            body = None
+        elif body is not None:
+            body.append(line.strip())
+    return comps
+
+
+def _table_copies(lines, n: int) -> list[str]:
+    """The ``copy`` ops in ``lines`` whose result is a whole (1, n, n)
+    table."""
+    pat = re.compile(rf"^%copy\.\d+ = \w+\[1,{n},{n}\]\S* copy\(")
+    return [ln for ln in lines if pat.match(ln)]
+
+
+def _module(text: str) -> str:
+    return text.split(None, 2)[1].rstrip(",")
+
+
+@pytest.mark.parametrize("successors", [False, True], ids=["dist", "succ"])
+def test_engine_solve_program_names(one_chip, successors):
+    from repro.apsp import ApspEngine
+
+    eng = ApspEngine(method="fused", backend="tpu", interpret=False,
+                     block_size=128)
+    entry = eng.plan_for(SMALL, 1, successors=successors)
+    text = entry.runner.lower(
+        _spec((1, SMALL, SMALL), jnp.float32, one_chip)).compile().as_text()
+    assert _module(text) == "jit_apsp_solve"
+    kernel = "fw_round_with_successors" if successors else "fw_round"
+    other = "fw_round" if successors else "fw_round_with_successors"
+    assert re.search(rf"^\s*(?:ROOT )?%{kernel}\.\d+ = ", text, re.M)
+    assert not re.search(rf"^\s*(?:ROOT )?%{other}\.\d+ = ", text, re.M)
+    comps = _computations(text)
+    loops = [re.search(r"body=%([\w.\-]+)", ln).group(1)
+             for ln in comps["ENTRY"] if " while(" in ln]
+    assert len(loops) == 1
+    body = comps[loops[0]]
+    # One whole-table copy per round: the distances, and the next hops.
+    copies = _table_copies(body, SMALL)
+    assert len(copies) == (2 if successors else 1), copies
+    assert any(" f32[" in c for c in copies)
+    if successors:
+        assert any(" s32[" in c for c in copies)
+        # The successor program's one copy of the input lies outside the
+        # loop, so the per-round reading does not count it.
+        assert _table_copies(comps["ENTRY"], SMALL)
+
+
+def _kernel_case(name, one_chip):
+    from repro.kernels.fw_round import fw_round_bordered
+
+    n, E = 1024, 4
+    f32 = _spec((n, n), jnp.float32, one_chip)
+    i32 = _spec((n, n), jnp.int32, one_chip)
+    b = _spec((), jnp.int32, one_chip)
+    uvw = (_spec((E,), jnp.int32, one_chip), _spec((E,), jnp.int32, one_chip),
+           _spec((E,), jnp.float32, one_chip))
+    return {
+        "fw_round": (lambda w, b: fw_round(w, b, interpret=False), f32, b),
+        "fw_round_with_successors": (
+            lambda w, sc, b: fw_round_with_successors(w, sc, b,
+                                                      interpret=False),
+            f32, i32, b),
+        "fw_round_bordered": (
+            lambda w: fw_round_bordered(w, interpret=False), f32),
+        "fw_repair": (
+            lambda d, u, v, w: fw_repair(d, u, v, w, interpret=False),
+            f32, *uvw),
+        "fw_repair_with_successors": (
+            lambda d, sc, u, v, w: fw_repair_with_successors(
+                d, sc, u, v, w, interpret=False),
+            f32, i32, *uvw),
+        "fw_repair_del_sweep": (
+            lambda d, rows: fw_repair_del_sweep(d, rows, block_size=128,
+                                                interpret=False),
+            f32, _spec((64,), jnp.int32, one_chip)),
+        "semiring_matmul": (
+            lambda a, b, c: semiring_matmul(a, b, c, interpret=False),
+            _spec((n, 512), jnp.float32, one_chip),
+            _spec((512, n), jnp.float32, one_chip), f32),
+    }[name]
+
+
+@pytest.mark.parametrize("name", [
+    "fw_round", "fw_round_with_successors", "fw_round_bordered", "fw_repair",
+    "fw_repair_with_successors", "fw_repair_del_sweep", "semiring_matmul"])
+def test_kernel_op_names(one_chip, name):
+    fn, *args = _kernel_case(name, one_chip)
+    text = _compiled_text(fn, *args)
+    ops = re.findall(r'^\s*(?:ROOT )?%([\w.\-]+) = .*'
+                     r'custom_call_target="tpu_custom_call"', text, re.M)
+    assert ops and all(re.fullmatch(rf"{name}\.\d+", op) for op in ops), ops
+
+
+@pytest.mark.parametrize("method,successors", [
+    ("repair", False), ("repair", True),
+    ("repair_del_mark", False), ("repair_del_mark", True),
+    ("repair_del", False), ("repair_del", True)])
+def test_engine_repair_program_names(one_chip, method, successors):
+    from repro.apsp import ApspEngine
+    from repro.apsp.engine import PlanKey
+
+    eng = ApspEngine(backend="tpu", interpret=False, block_size=128)
+    m, E, a_pad = SMALL, 4, 64
+    key = PlanKey(n_padded=m, batch=1, dtype="float32",
+                  semiring=eng.semiring.name, method=method, block_size=128,
+                  bk=32 if method == "repair_del" else 0, batch_block=None,
+                  successors=successors,
+                  edges=a_pad if method == "repair_del" else E,
+                  backend="tpu")
+    build = {"repair": eng._build_repair,
+             "repair_del_mark": eng._build_repair_del_mark,
+             "repair_del": eng._build_repair_del_sweep}[method]
+    d = _spec((m, m), jnp.float32, one_chip)
+    sc = [_spec((m, m), jnp.int32, one_chip)] if successors else []
+    edge = [_spec((E,), jnp.int32, one_chip)] * 2 + [
+        _spec((E,), jnp.float32, one_chip)]
+    args = {
+        "repair": [d, *sc, *edge],
+        "repair_del_mark": [d, *sc, d, *edge, _spec((), jnp.int32, one_chip)],
+        "repair_del": [d, *sc, _spec((a_pad,), jnp.int32, one_chip)],
+    }[method]
+    text = build(key).runner.lower(*args).compile().as_text()
+    assert _module(text) == {"repair": "jit_apsp_repair",
+                             "repair_del_mark": "jit_apsp_repair_del_mark",
+                             "repair_del": "jit_apsp_repair_del_sweep"}[method]
