@@ -57,6 +57,10 @@ closed distance bands plus their successor bands), so
 ``solve(successors=True, method="fused")`` no longer falls back to the
 multi-dispatch blocked path.  Outputs bit-match
 ``fw_blocked_with_successors`` (distances and successor matrices).
+Phase 3 (``_relax_succ_grouped``) rotates its three fixed operands once
+per ``_PICK_GROUP`` steps and picks with static slices; phases 1–2 still
+rotate on every step, since the tile they close is one of their operands
+and changes inside the loop.
 
 VMEM: scratch is ``bb·2·s·n`` words + the double-buffered (bb,s,s) in/out
 tiles — ``plan.fused_round_vmem_bytes(batch=bb)``; successor tracking
@@ -101,9 +105,11 @@ from jax.experimental import pallas as pl
 
 from repro.core.semiring import MIN_PLUS, Semiring
 from repro.kernels.minplus_matmul import (
+    _PICK_GROUP,
     Variant,
     _col,
     _fit_block,
+    _rotate,
     _row,
     _stage_chunks,
 )
@@ -293,6 +299,29 @@ def _relax_succ(k, t, ts, a, asucc, bb, mosaic: bool = False):
     )
 
 
+def _relax_succ_grouped(t, ts, a, asucc, bb):
+    """Relax (t, ts) against all K steps of the fixed phase-3 operands,
+    ``_PICK_GROUP`` at a time (``minplus_matmul._stage_chunks``' scheme).
+
+    Each group rotates ``a``, ``asucc`` and ``bb`` once and runs its steps
+    with static picks; the ascending-k ``_relax_succ`` chain is unchanged.
+    A rotate is a pure data movement, so casting it back is exact.
+    """
+    K = a.shape[-1]
+    G = _PICK_GROUP if K % _PICK_GROUP == 0 else 1
+
+    def body(c, carry):
+        ag = _rotate(a, c * G, -1).astype(a.dtype)
+        asg = _rotate(asucc, c * G, -1).astype(asucc.dtype)
+        bg = _rotate(bb, c * G, -2).astype(bb.dtype)
+        t, ts = carry
+        for kk in range(G):
+            t, ts = _relax_succ(kk, t, ts, ag, asg, bg)
+        return t, ts
+
+    return jax.lax.fori_loop(0, K // G, body, (t, ts))
+
+
 def _round_succ_kernel(
     oi_ref, oj_ref, w_ref, s_ref, ow_ref, os_ref,
     rw_ref, cw_ref, rs_ref, cs_ref,
@@ -366,12 +395,7 @@ def _round_succ_kernel(
         bsucc = rs_ref[lead + (slice(None), pl.ds(j * s, s))]
         c = jnp.where(i == b, bb, jnp.where(j == b, a, w_ref[...]))
         cs = jnp.where(i == b, bsucc, jnp.where(j == b, asucc, s_ref[...]))
-
-        def body(k, carry):
-            t, ts = carry
-            return _relax_succ(k, t, ts, a, asucc, bb, mosaic=True)
-
-        c, cs = jax.lax.fori_loop(0, s, body, (c, cs))
+        c, cs = _relax_succ_grouped(c, cs, a, asucc, bb)
         ow_ref[...] = c
         os_ref[...] = cs
 

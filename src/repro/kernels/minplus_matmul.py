@@ -54,9 +54,11 @@ Variant = Literal["fori", "unroll", "broadcast"]
 # is exact (bf16 ⊂ f32, int16 ⊂ int32).
 _WIDE = {jnp.dtype(jnp.bfloat16): jnp.float32, jnp.dtype(jnp.int16): jnp.int32}
 
-# Rank-1 steps per rotate in the kernels' phase-3 loop.  With a rotate per
-# step, the rotates and not the rank-1 arithmetic bounded phase 3 on a v5e
-# (host-clock solve times only; no kernel trace yet).
+# Rank-1 steps per rotate in phase 3 of both fused rounds (``_stage_chunks``
+# and ``fw_round._relax_succ_grouped``).  With a rotate per step, the
+# rotates and not the rank-1 arithmetic bounded phase 3 on a v5e; the
+# benchmark's kernel traces (``round.device_s``, ``succ_round.device_s``)
+# now show it per kernel.
 _PICK_GROUP = 8
 
 

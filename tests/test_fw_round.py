@@ -24,7 +24,7 @@ import pytest
 from repro.apsp import pack_reachability, plan, solve, unpack_reachability
 from repro.core.floyd_warshall import fw_naive
 from repro.core.graph import random_digraph
-from repro.core.paths import fw_blocked_with_successors
+from repro.core.paths import _init_successors, fw_blocked_with_successors
 from repro.core.semiring import (
     I16_INF,
     LOWERED_SEMIRINGS,
@@ -47,6 +47,7 @@ from repro.kernels.ref import (
     fw_phase2_col_ref,
     fw_phase2_row_ref,
     fw_round_bordered_ref,
+    fw_round_with_successors_ref,
 )
 
 
@@ -223,6 +224,44 @@ def test_fused_successors_batched(lowering):
         d_ref, s_ref = fw_blocked_with_successors(wb[i], block_size=s)
         assert np.array_equal(np.asarray(d_got[i]), np.asarray(d_ref))
         assert np.array_equal(np.asarray(s_got[i]), np.asarray(s_ref))
+
+
+def _tie_graph(n, seed):
+    """Integer weights from {1, 2, 3} with 30% missing edges: equal-length
+    paths abound (ties), and a few vertices have no out-edges, so their
+    rows stay unreachable (next hop -1)."""
+    rng = np.random.default_rng(seed)
+    w = rng.choice(np.float32([1.0, 2.0, 3.0]), size=(n, n))
+    w[rng.random((n, n)) < 0.3] = np.inf
+    w[rng.choice(n, size=3, replace=False)] = np.inf
+    np.fill_diagonal(w, 0.0)
+    return w
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["single", "batched"])
+@pytest.mark.parametrize("s", [32, 12], ids=["grouped", "ungrouped"])
+def test_successor_round_ties_bitwise(s, batched):
+    """Phase 3 picks its columns/rows a group at a time (a group per 8 steps
+    when 8 divides the block, else per step); on tie-heavy weights every
+    round still bit-matches the XLA twin, and the whole solve the blocked
+    successor path, next hops included."""
+    n = 96
+    w = jnp.asarray(np.stack([_tie_graph(n, 40 + i) for i in range(2)])
+                    if batched else _tie_graph(n, 40))
+    d, sc = w, _init_successors(w)
+    for b in range(n // s):
+        d_want, s_want = fw_round_with_successors_ref(d, sc, b, block_size=s)
+        d, sc = fw_round_with_successors(d, sc, b, block_size=s,
+                                         interpret=True)
+        assert np.array_equal(np.asarray(d), np.asarray(d_want))
+        assert np.array_equal(np.asarray(sc), np.asarray(s_want))
+    assert (np.asarray(sc) == -1).any()  # unreachable pairs were exercised
+    ws = w if batched else w[None]
+    d_got, s_got = fw_staged_with_successors(w, block_size=s, interpret=True)
+    for wi, di, si in zip(ws, d_got.reshape(ws.shape), s_got.reshape(ws.shape)):
+        d_ref, s_ref = fw_blocked_with_successors(wi, block_size=s)
+        assert np.array_equal(np.asarray(di), np.asarray(d_ref))
+        assert np.array_equal(np.asarray(si), np.asarray(s_ref))
 
 
 def test_fw_round_with_successors_rejects_bad_shapes():
