@@ -25,6 +25,7 @@ from repro.apsp.api import (
     APSPResult,
     NegativeCycleError,
     negative_cycle_mask,
+    pack_edges,
     pack_reachability,
     solve,
     unpack_reachability,
@@ -54,6 +55,7 @@ __all__ = [
     "distributed_plan",
     "fw_kleene",
     "negative_cycle_mask",
+    "pack_edges",
     "pack_reachability",
     "plan",
     "recursive_plan",

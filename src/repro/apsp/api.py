@@ -134,14 +134,71 @@ def pack_reachability(w) -> jax.Array:
         raise ValueError(f"w must be (n,n) or (B,n,n), got {arr.shape}")
     B, n, _ = arr.shape
     G = -(-B // PACK_LANES)
-    bits = (arr != 0).astype(jnp.uint32)
-    if G * PACK_LANES != B:
-        bits = jnp.pad(bits, ((0, G * PACK_LANES - B), (0, 0), (0, 0)))
-    shifts = jnp.arange(PACK_LANES, dtype=jnp.uint32)[None, :, None, None]
-    words = jnp.bitwise_or.reduce(
-        bits.reshape(G, PACK_LANES, n, n) << shifts, axis=1
-    )
+
+    # One graph at a time, so nothing larger than the words is made.
+    def fold(g, words):
+        bit = (arr[g] != 0).astype(jnp.uint32) << (g % PACK_LANES)
+        return words.at[g // PACK_LANES].set(words[g // PACK_LANES] | bit)
+
+    words = jax.lax.fori_loop(0, B, fold, jnp.zeros((G, n, n), jnp.uint32))
     return jax.lax.bitcast_convert_type(words, jnp.int32)
+
+
+def pack_edges(src, dst, n: int) -> jax.Array:
+    """Pack per-graph edge lists into the or_and_packed word table.
+
+    src, dst: (B, E) int arrays, or (E,) for one graph: edge ``e`` of graph
+    ``g`` runs ``src[g, e] → dst[g, e]``.  Duplicate edges and self-loops
+    are legal; an edge with an endpoint outside [0, n) is dropped.  Returns
+    the (ceil(B/32), n, n) int32 words of ``pack_reachability``'s layout
+    (graph g at word g // 32, bit g % 32) with every graph's diagonal bit
+    set (the ⊗-identity, -1, in a word of 32 graphs), so the table is ready
+    for the packed closure.
+
+    Peak memory is the words plus O(B·E): each graph's edges are sorted to
+    drop repeats, after which the bits landing on one word entry are
+    distinct and a scatter-add of them is their OR.  No (B, n, n) array is
+    made.  Traceable; ``ApspEngine.pack_edges`` runs it jitted.
+    """
+    src = jnp.asarray(src)
+    dst = jnp.asarray(dst)
+    if src.ndim == 1:
+        src, dst = src[None], dst[None]
+    if src.ndim != 2 or src.shape != dst.shape or src.shape[0] < 1:
+        raise ValueError(
+            f"src and dst must both be (E,) or (B, E) with B >= 1, got "
+            f"{src.shape} and {dst.shape}"
+        )
+    if not (jnp.issubdtype(src.dtype, jnp.integer)
+            and jnp.issubdtype(dst.dtype, jnp.integer)):
+        raise ValueError(
+            f"src and dst must be integer vertex ids, got {src.dtype} and "
+            f"{dst.dtype}"
+        )
+    B = src.shape[0]
+    G = -(-B // PACK_LANES)
+    s, d = jax.lax.sort(
+        (src.astype(jnp.int32), dst.astype(jnp.int32)), dimension=1,
+        num_keys=2,
+    )
+    repeat = jnp.zeros_like(s, dtype=bool).at[:, 1:].set(
+        (s[:, 1:] == s[:, :-1]) & (d[:, 1:] == d[:, :-1])
+    )
+    inside = (s >= 0) & (s < n) & (d >= 0) & (d < n)
+    g = jnp.arange(B, dtype=jnp.int32)[:, None]
+    bit = jnp.left_shift(jnp.int32(1), g % PACK_LANES)
+    bit = jnp.where(repeat | ~inside, 0, bit)
+    words = jnp.zeros((G, n, n), jnp.int32).at[
+        jnp.broadcast_to(g // PACK_LANES, s.shape),
+        jnp.clip(s, 0, n - 1), jnp.clip(d, 0, n - 1),
+    ].add(bit)
+    # Every graph's diagonal holds its ⊗-identity bit; lanes past B stay
+    # empty graphs, as in pack_reachability.
+    lanes = jnp.minimum(B - PACK_LANES * jnp.arange(G), PACK_LANES)
+    diag = jnp.where(lanes == PACK_LANES, -1,
+                     jnp.left_shift(1, lanes % PACK_LANES) - 1)
+    idx = jnp.arange(n)
+    return words.at[:, idx, idx].set(diag.astype(jnp.int32)[:, None])
 
 
 def unpack_reachability(p, count: int | None = None, *, dtype=jnp.float32):

@@ -31,8 +31,10 @@ sizes).  ``ApspEngine`` is the session object for that regime:
     across devices with the same no-retrace guarantee.
   * **trace names** — every solve runner is jitted as ``apsp_solve`` (its
     compiled module is ``jit_apsp_solve``), the repair runners as
-    ``apsp_repair``, ``apsp_repair_del_mark`` and ``apsp_repair_del_sweep``;
-    ``solve`` and ``solve_many`` run inside the host span ``apsp.solve``.
+    ``apsp_repair``, ``apsp_repair_del_mark`` and ``apsp_repair_del_sweep``,
+    the edge-list packer as ``apsp_pack``; ``solve`` and ``solve_many`` run
+    inside the host span ``apsp.solve``, ``pack_edges`` inside
+    ``apsp.pack``.
     A profiler trace thus tells the solve program's device time apart
     from the engine's own work around it (tests/test_tpu_compile.py pins
     the names; the benchmark's ``solve.*`` metrics read them).
@@ -44,6 +46,7 @@ serving layer serializes refreshes).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Sequence
 
 import jax
@@ -62,6 +65,7 @@ from repro.apsp.api import (
     _pad,
     _resolve_semiring,
     _resolve_shape,
+    pack_edges,
 )
 from repro.core.floyd_warshall import fw_blocked, fw_naive, fw_numpy
 from repro.core.paths import fw_blocked_with_successors, fw_with_successors
@@ -120,7 +124,7 @@ class EngineStats:
     hits: int = 0
     misses: int = 0
     solves: int = 0
-    graphs_solved: int = 0
+    graphs_solved: int = 0   # a packed engine counts 32 per word (lanes)
     repairs: int = 0         # rank-1 repair dispatches (ApspEngine.repair)
     edges_repaired: int = 0  # real (unpadded) edge updates absorbed by them
     repair_rejects: int = 0  # should_repair fast-rejects (edge worsenings)
@@ -129,6 +133,9 @@ class EngineStats:
     repair_del_noops: int = 0      # empty affected set — no sweep dispatched
     repair_del_fallbacks: int = 0  # marked, then re-solved (cost/semiring)
     edges_deleted: int = 0         # real deletions absorbed (sweeps + noops)
+    packs: int = 0          # edge-list packs (ApspEngine.pack_edges)
+    graphs_packed: int = 0  # graphs those packs wrote, one per bit lane
+    edges_packed: int = 0   # edge tuples they read, repeats included
 
 
 class ApspEngine:
@@ -173,7 +180,8 @@ class ApspEngine:
         saturating int16 tropical lowering, ``dtype=jnp.bfloat16`` casts
         weights to bf16, and ``packed=True`` (or_and only) serves the
         bit-packed int32 closure — engine inputs are then *pre-packed*
-        bit-plane words (``api.pack_reachability``; the stateless
+        bit-plane words (``pack_edges`` makes them from edge lists on the
+        device, ``api.pack_reachability`` from dense graphs; the stateless
         ``solve(packed=True)`` owns pack/unpack, the engine stays in word
         space so cached plans see the physical shapes).  Plan keys carry
         the lowered semiring name + storage dtype, so an f32 and an int16
@@ -518,7 +526,7 @@ class ApspEngine:
             if self.validate and _is_min_plus(self.semiring):
                 _check_negative_cycles(dist, batched)
             self.stats.solves += 1
-            self.stats.graphs_solved += B
+            self.stats.graphs_solved += B * self.semiring.lanes
             return self._result(entry, dist, succ, n)
 
     def solve_many(
@@ -576,8 +584,30 @@ class ApspEngine:
                     s_i = succ[k, :n_i, :n_i] if succ is not None else None
                     results[i] = self._result(entry, d_i, s_i, n_i)
             self.stats.solves += len(buckets)
-            self.stats.graphs_solved += len(arrs)
+            self.stats.graphs_solved += len(arrs) * self.semiring.lanes
             return results  # type: ignore[return-value]
+
+    def pack_edges(self, src, dst, n: int) -> jax.Array:
+        """Edge lists → the packed engine's (G, n, n) int32 word table.
+
+        src, dst: (B, E) int32 edge arrays of B ≤ 32·G graphs (or (E,) for
+        one); see ``api.pack_edges`` for the layout.  The table is made on
+        the device by the program ``apsp_pack`` (module ``jit_apsp_pack``)
+        inside the host span ``apsp.pack``, ready for ``solve``.
+        """
+        if not self.semiring.packed:
+            raise ValueError(
+                f"pack_edges builds or_and_packed words; this engine runs "
+                f"{self.semiring.name!r} (construct it with "
+                f"semiring='or_and', packed=True)"
+            )
+        with jax.profiler.TraceAnnotation("apsp.pack"):
+            words = apsp_pack(src, dst, n)
+            shape = np.shape(src)
+            self.stats.packs += 1
+            self.stats.graphs_packed += 1 if len(shape) == 1 else shape[0]
+            self.stats.edges_packed += int(np.prod(shape))
+            return words
 
     # -------------------------------------------------------------- repair
     def repair(self, dist, updates, *, succ=None) -> APSPResult:
@@ -1094,6 +1124,12 @@ class ApspEngine:
             semiring=entry.key.semiring, block_size=entry.key.block_size,
             n=n, padded_n=entry.key.n_padded,
         )
+
+
+@functools.partial(jax.jit, static_argnames="n")
+def apsp_pack(src, dst, n: int) -> jax.Array:
+    """``api.pack_edges`` as one program, named for the device trace."""
+    return pack_edges(src, dst, n)
 
 
 def negative_cycle_mask_padded(dist, ns: Sequence[int]) -> np.ndarray:

@@ -360,3 +360,29 @@ def test_engine_repair_program_names(one_chip, method, successors):
     assert _module(text) == {"repair": "jit_apsp_repair",
                              "repair_del_mark": "jit_apsp_repair_del_mark",
                              "repair_del": "jit_apsp_repair_del_sweep"}[method]
+
+
+def test_engine_packed_solve_program(one_chip):
+    # The packed reachability closure as the engine plans it on a TPU at
+    # n=16384: one int32 word table of 32 graphs, the or_and_packed round.
+    from repro.apsp import ApspEngine
+
+    eng = ApspEngine(semiring="or_and", packed=True, method="fused",
+                     backend="tpu", interpret=False)
+    entry = eng.plan_for(N, 1, dtype=jnp.int32)
+    text = entry.runner.lower(
+        _spec((1, N, N), jnp.int32, one_chip)).compile().as_text()
+    assert _module(text) == "jit_apsp_solve"
+    assert re.search(r"^\s*(?:ROOT )?%fw_round\.\d+ = s32\[", text, re.M)
+
+
+def test_edge_list_packer_program(one_chip):
+    # The packer at the reach16k deployment: 32 graphs of 16 * n edges.
+    from repro.apsp.engine import apsp_pack
+
+    E = 16 * N
+    text = apsp_pack.lower(
+        _spec((32, E), jnp.int32, one_chip),
+        _spec((32, E), jnp.int32, one_chip), n=N).compile().as_text()
+    assert _module(text) == "jit_apsp_pack"
+    assert re.search(rf"^\s*ROOT .* = s32\[1,{N},{N}\]", text, re.M)
