@@ -488,9 +488,9 @@ class ApspEngine:
             else:
                 # "tpu" — and "ref", whose XLA twin replays the fused
                 # schedule, so the TPU models still describe the plan.
-                entry.vmem_bytes = scale * plan.fused_round_vmem_bytes(
+                entry.vmem_bytes = plan.fused_round_vmem_bytes(
                     key.n_padded, s, bk, word=word, variant=self.variant,
-                    batch=bb or 1,
+                    batch=bb or 1, successors=key.successors,
                 )
                 entry.hbm_bytes_per_round = scale * plan.fused_round_hbm_bytes(
                     key.n_padded, s, word=word, batch=key.batch,

@@ -162,6 +162,14 @@ def dist_round_comm_bytes(
 LIVE_TILES = 12
 
 
+def lane_cache_bytes(s: int, *, word: int = 4, variant: str = "fori") -> int:
+    """Bytes per graph of the round's phase-3 lane-broadcast cache
+    (``kernels.fw_round._fill_lane_cache``): s columns, each broadcast to
+    an (s, s) tile of 32-bit words (16-bit storage widens).  Only the
+    "fori" variant keeps it."""
+    return s ** 3 * max(word, 4) if variant == "fori" else 0
+
+
 def bordered_round_vmem_bytes(
     rows: int, cols: int, s: int, bk: int, *, word: int = 4,
     variant: str = "fori", batch: int = 1,
@@ -170,13 +178,17 @@ def bordered_round_vmem_bytes(
 
     Same shape as ``fused_round_vmem_bytes`` on a rectangular (rows, cols)
     bordered local matrix: the two closed border bands in persistent scratch
-    (s·cols + rows·s words) plus the double-buffered (s,s) in/out tiles and
-    the body's live tiles, times the batch block.
+    (s·cols + rows·s words) plus the double-buffered (s,s) in/out tiles,
+    the body's live tiles and the lane-broadcast cache, times the batch
+    block.
     """
     bands = s * cols + rows * s
     tiles = 2 * 2 * s * s
     transient = s * bk * s if variant == "broadcast" else 0
-    return batch * (bands + tiles + LIVE_TILES * s * s + transient) * word
+    words = bands + tiles + LIVE_TILES * s * s + transient
+    return batch * (
+        words * word + lane_cache_bytes(s, word=word, variant=variant)
+    )
 
 
 def auto_bordered_batch_block(
@@ -341,22 +353,31 @@ def phase3_vmem_bytes(
 
 def fused_round_vmem_bytes(
     n: int, s: int, bk: int, *, word: int = 4, variant: str = "fori",
-    batch: int = 1,
+    batch: int = 1, successors: bool = False,
 ) -> int:
     """VMEM per fused-round grid step (``kernels.fw_round``).
 
     Persistent scratch holds both closed pivot bands (2·s·n words); the
     (s,s) input and output tiles are each double-buffered by the Pallas
     pipeline; the kernel body's live values take ``LIVE_TILES`` more (s,s)
-    tiles.  The "broadcast" phase-3 variant additionally materializes an
-    (s, bk, s) product transient.  ``batch`` is the batch *block* of the
-    batched grid: every term carries a per-graph leading dim, so the
-    footprint scales linearly.  See EXPERIMENTS.md §Fused round.
+    tiles.  The "fori" variant's phase 3 keeps the lane-broadcast cache,
+    s³ 32-bit words (``lane_cache_bytes``); the "broadcast" variant instead
+    materializes an (s, bk, s) product transient.  ``batch`` is the batch
+    *block* of the batched grid: every term carries a per-graph leading
+    dim, so the footprint scales linearly.  ``successors=True`` models
+    ``fw_round_with_successors``: twice the bands, tiles and live values
+    (distances and next hops), and no cache.  See EXPERIMENTS.md §Fused
+    round.
     """
     bands = 2 * s * n
     tiles = 2 * 2 * s * s
     transient = s * bk * s if variant == "broadcast" else 0
-    return batch * (bands + tiles + LIVE_TILES * s * s + transient) * word
+    words = bands + tiles + LIVE_TILES * s * s + transient
+    if successors:
+        return batch * 2 * words * word
+    return batch * (
+        words * word + lane_cache_bytes(s, word=word, variant=variant)
+    )
 
 
 def fused_round_hbm_bytes(
@@ -500,18 +521,18 @@ def auto_batch_block(
     The batched round's working set scales linearly in the batch block
     (per-graph scratch bands), so the best block is simply the fattest one
     the budget admits — bigger blocks mean fewer grid steps and wider
-    VPU-lane occupancy per step.  ``successors=True`` doubles the footprint
-    (distance + successor bands).
+    VPU-lane occupancy per step.  ``successors=True`` plans the successor
+    round (``fused_round_vmem_bytes``).
     """
     if B < 1:
         raise ValueError(f"batch size must be >= 1, got {B}")
     vmem_budget = _vmem_budget(vmem_budget)
-    scale = 2 if successors else 1
     for bb in range(B, 0, -1):
         if B % bb:
             continue
-        if scale * fused_round_vmem_bytes(
-            n, s, bk, word=word, variant=variant, batch=bb
+        if fused_round_vmem_bytes(
+            n, s, bk, word=word, variant=variant, batch=bb,
+            successors=successors,
         ) <= vmem_budget:
             return bb
     return 1

@@ -62,10 +62,18 @@ per ``_PICK_GROUP`` steps and picks with static slices; phases 1–2 still
 rotate on every step, since the tile they close is one of their operands
 and changes inside the loop.
 
+Phase 3 of the "fori" variant keeps a lane-broadcast cache: phase 3
+visits tiles row-major and its a-operand (the col-band tile) depends on
+the row alone, so each row's first step broadcasts that operand's s
+columns across the lanes once, into an (s, s, s) scratch, and every step
+of the row loads them (``_fill_lane_cache``, ``_relax_lane_cache``).  No
+step then broadcasts across lanes, the XLU work that paced phase 3.
+
 VMEM: scratch is ``bb·2·s·n`` words + the double-buffered (bb,s,s) in/out
-tiles — ``plan.fused_round_vmem_bytes(batch=bb)``; successor tracking
-doubles it.  ``plan.auto_batch_block`` picks the largest batch block that
-fits the budget.
+tiles + ``bb·s³`` 32-bit words of lane cache —
+``plan.fused_round_vmem_bytes(batch=bb)``; successor tracking doubles the
+bands and tiles and keeps no cache.  ``plan.auto_batch_block`` picks the
+largest batch block that fits the budget.
 
 ``fw_round_bordered`` is the distributed form of the same kernel: each
 device of an R×C mesh holds an (n_r, n_c) block of W, and per round the raw
@@ -106,6 +114,7 @@ from jax.experimental import pallas as pl
 from repro.core.semiring import MIN_PLUS, Semiring
 from repro.kernels.minplus_matmul import (
     _PICK_GROUP,
+    _WIDE,
     Variant,
     _col,
     _fit_block,
@@ -213,9 +222,54 @@ def _close_col_panel(
     return jax.lax.fori_loop(0, s, body, p)
 
 
+def _fill_lane_cache(a, cache_ref, lead):
+    """Entry k of ``cache_ref`` ← column k of ``a`` broadcast across lanes.
+
+    The one place phase 3 of the round broadcasts a column: a group rotate
+    per ``_PICK_GROUP`` columns, then a static lane broadcast per column.
+    """
+    K = a.shape[-1]
+    G = _PICK_GROUP if K % _PICK_GROUP == 0 else 1
+
+    def body(c, carry):
+        ag = _rotate(a, c * G, -1).astype(cache_ref.dtype)
+        for kk in range(G):
+            cache_ref[lead + (c * G + kk,)] = jnp.broadcast_to(
+                _col(ag, kk), ag.shape
+            )
+        return carry
+
+    jax.lax.fori_loop(0, K // G, body, 0)
+
+
+def _relax_lane_cache(acc, cache_ref, row_ref, col0, semiring, lead):
+    """``_stage_chunks``' ascending-k chain ⊕(acc, ⊗(a[:,k], b[k,:])) with
+    column k of ``a`` read from ``cache_ref`` (``_fill_lane_cache``) and the
+    b-operand, the (s, s) block of ``row_ref`` at lane offset ``col0``,
+    read ``_PICK_GROUP`` rows (one sublane tile) per aligned load: plain
+    loads and a sublane broadcast per step, no lane broadcast and no
+    rotate.  The cache holds exact copies, so the chain — and every bit —
+    is ``_stage_chunks``'.
+    """
+    K = cache_ref.shape[-3]
+    G = _PICK_GROUP if K % _PICK_GROUP == 0 else 1
+
+    def body(c, acc):
+        bg = row_ref[lead + (pl.ds(pl.multiple_of(c * G, G), G),
+                             pl.ds(col0, K))]
+        for kk in range(G):
+            acc = semiring.add(acc, semiring.mul(
+                cache_ref[lead + (c * G + kk,)].astype(acc.dtype),
+                bg[..., kk:kk + 1, :],
+            ))
+        return acc
+
+    return jax.lax.fori_loop(0, K // G, body, acc)
+
+
 def _round_kernel(
-    oi_ref, oj_ref, own_ref, w_ref, o_ref, row_ref, col_ref,
-    *, tr: int, tc: int, s: int, bk: int, semiring: Semiring,
+    oi_ref, oj_ref, own_ref, w_ref, o_ref, row_ref, col_ref, *cache,
+    tr: int, tc: int, s: int, bk: int, semiring: Semiring,
     variant: Variant, step_axis: int = 0,
 ):
     """One multi-stage round on a (tr, tc) tile grid.
@@ -228,6 +282,12 @@ def _round_kernel(
     scratch values are spliced over those copies so non-idempotent ⊕ never
     re-relaxes an already-closed band.  (-1, -1) — the square case — makes
     every echo a no-op.
+
+    ``cache`` is the lane-broadcast scratch of the "fori" variant
+    (``_lane_cache_scratch``): phase 3 visits tiles row-major and its
+    a-operand depends on the row alone, so the row's first step (j == 0)
+    broadcasts the a-operand's columns once and every step of the row
+    loads them.
     """
     g = pl.program_id(step_axis)
     i = oi_ref[g]
@@ -279,8 +339,19 @@ def _round_kernel(
             (i == b) | (i == pr), bb,
             jnp.where((j == b) | (j == pc), a, w_ref[...]),
         )
-        o_ref[...] = _stage_chunks(
-            c, a, bb, bk, semiring, variant, mosaic=True
+        if not cache:
+            o_ref[...] = _stage_chunks(
+                c, a, bb, bk, semiring, variant, mosaic=True
+            )
+            return
+        (cache_ref,) = cache
+
+        @pl.when(j == 0)
+        def _fill():
+            _fill_lane_cache(a, cache_ref, lead)
+
+        o_ref[...] = _relax_lane_cache(
+            c, cache_ref, row_ref, pl.multiple_of(j * s, s), semiring, lead
         )
 
 
@@ -417,6 +488,16 @@ def _resolve_batch_block(B: int, n: int, s: int, batch_block: int | None,
     )
 
 
+def _lane_cache_scratch(pltpu, variant, s, dtype, lead=()):
+    """The round's phase-3 lane-broadcast cache, ``lead + (s, s, s)``
+    32-bit words (16-bit storage widens, as picks do), for the "fori"
+    variant; the "unroll" and "broadcast" variants keep ``_stage_chunks``'
+    bk-chunk lowerings and no cache."""
+    if variant != "fori":
+        return []
+    return [pltpu.VMEM(lead + (s, s, s), _WIDE.get(jnp.dtype(dtype), dtype))]
+
+
 def _batch_grid_spec(pltpu, B, bb, s, steps, scratch, extra_in=0,
                      num_prefetch=3):
     """PrefetchScalarGridSpec for the batched round: leading batch grid dim,
@@ -491,7 +572,8 @@ def fw_round(
         grid_spec = _batch_grid_spec(
             pltpu, B, bb, s, T * T + 2 * T - 1,
             [pltpu.VMEM((bb, s, n), w.dtype),  # closed row bands, per graph
-             pltpu.VMEM((bb, n, s), w.dtype)],  # closed col bands, per graph
+             pltpu.VMEM((bb, n, s), w.dtype)]  # closed col bands, per graph
+            + _lane_cache_scratch(pltpu, variant, s, w.dtype, (bb,)),
         )
         step_axis, semantics = 1, ("arbitrary", "arbitrary")
     else:
@@ -504,7 +586,7 @@ def fw_round(
             scratch_shapes=[
                 pltpu.VMEM((s, n), w.dtype),  # closed row band (diag at col b)
                 pltpu.VMEM((n, s), w.dtype),  # closed col band (diag at row b)
-            ],
+            ] + _lane_cache_scratch(pltpu, variant, s, w.dtype),
         )
         step_axis, semantics = 0, ("arbitrary",)
     kern = functools.partial(
@@ -610,7 +692,8 @@ def fw_round_bordered(
         grid_spec = _batch_grid_spec(
             pltpu, B, bb, s, steps,
             [pltpu.VMEM((bb, s, cols), w.dtype),  # closed border row band
-             pltpu.VMEM((bb, rows, s), w.dtype)],  # closed border col band
+             pltpu.VMEM((bb, rows, s), w.dtype)]  # closed border col band
+            + _lane_cache_scratch(pltpu, variant, s, w.dtype, (bb,)),
         )
         step_axis, semantics = 1, ("arbitrary", "arbitrary")
     else:
@@ -623,7 +706,7 @@ def fw_round_bordered(
             scratch_shapes=[
                 pltpu.VMEM((s, cols), w.dtype),  # closed border row band
                 pltpu.VMEM((rows, s), w.dtype),  # closed border col band
-            ],
+            ] + _lane_cache_scratch(pltpu, variant, s, w.dtype),
         )
         step_axis, semantics = 0, ("arbitrary",)
     kern = functools.partial(
@@ -679,14 +762,16 @@ def fw_round_with_successors(
     compat.check_tpu_lowering(w.dtype, interpret, s)
     T = n // s
     oi, oj = _round_order(b, T)
-    word = jnp.dtype(w.dtype).itemsize + jnp.dtype(succ.dtype).itemsize
+    word = max(jnp.dtype(w.dtype).itemsize, jnp.dtype(succ.dtype).itemsize)
     out_shape = (
         jax.ShapeDtypeStruct(w.shape, w.dtype),
         jax.ShapeDtypeStruct(succ.shape, succ.dtype),
     )
     if batched:
         B = w.shape[0]
-        bb = _resolve_batch_block(B, n, s, batch_block, word=word)
+        bb = _resolve_batch_block(
+            B, n, s, batch_block, word=word, successors=True
+        )
         grid_spec = _batch_grid_spec(
             pltpu, B, bb, s, T * T + 2 * T - 1,
             [pltpu.VMEM((bb, s, n), w.dtype),

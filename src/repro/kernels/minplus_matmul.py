@@ -54,11 +54,12 @@ Variant = Literal["fori", "unroll", "broadcast"]
 # is exact (bf16 ⊂ f32, int16 ⊂ int32).
 _WIDE = {jnp.dtype(jnp.bfloat16): jnp.float32, jnp.dtype(jnp.int16): jnp.int32}
 
-# Rank-1 steps per rotate in phase 3 of both fused rounds (``_stage_chunks``
-# and ``fw_round._relax_succ_grouped``).  With a rotate per step, the
-# rotates and not the rank-1 arithmetic bounded phase 3 on a v5e; the
-# benchmark's kernel traces (``round.device_s``, ``succ_round.device_s``)
-# now show it per kernel.
+# Rank-1 steps per rotate in the kernels' "fori" loops (``_stage_chunks``,
+# ``fw_round._relax_succ_grouped``, and the plain round's lane cache, whose
+# 8-row loads of the b-operand are one sublane tile of 32-bit words).  The
+# grouping took the per-step rotates out; on a v5e the lane broadcast of
+# column k that each step still makes is what paces such a loop (the plain
+# round caches it: ``fw_round._fill_lane_cache``).
 _PICK_GROUP = 8
 
 

@@ -56,6 +56,18 @@ def test_fw_distributed_direct_bitwise():
     assert "OK bitwise" in out
 
 
+@pytest.mark.parametrize("semiring", ["min_plus", "plus_mul"])
+def test_distributed_lane_cache_rows_bitwise(semiring):
+    """A 2×2 mesh with 16-wide tiles gives each device a bordered block of
+    five tile rows, each relaxed against its own lane cache, for a batch of
+    two graphs: the sharded solve still bit-matches the fused one."""
+    out = run_check(
+        "--devices", "4", "--n", "128", "--bs", "16", "--method", "solve",
+        "--bitwise", "--batch", "2", "--semiring", semiring,
+    )
+    assert "OK bitwise" in out
+
+
 def test_solve_distributed_batched_bitwise():
     """(B, n, n) input shards the trailing dims; every graph bit-matches
     its single-device fused solve through one sharded batch."""

@@ -47,6 +47,7 @@ from repro.kernels.ref import (
     fw_phase2_col_ref,
     fw_phase2_row_ref,
     fw_round_bordered_ref,
+    fw_round_ref,
     fw_round_with_successors_ref,
 )
 
@@ -129,6 +130,40 @@ def test_fw_round_matches_legacy_round_sequence(n, s, bk):
         wl = legacy_round(wl, b)
         wf = fw_round(wf, b, block_size=s, bk=bk, interpret=True)
         assert np.array_equal(np.asarray(wl), np.asarray(wf)), f"round {b}"
+
+
+# ------------------------------------------- phase 3's lane-broadcast cache
+@pytest.mark.parametrize(
+    "case", ["min_plus", "plus_mul", "or_and_packed", "batched"])
+def test_fw_round_lane_cache_bitwise(case):
+    """Phase 3 broadcasts a tile row's a-operand columns once, into a cache
+    rebuilt at the row's first step, and every step of the row loads them.
+    With T = 4 tile rows (four fills of the cache a round) and the pivot at
+    the first, a middle and the last tile, each round bit-matches the XLA
+    twin and the uncached bk-chunk lowering (variant="unroll")."""
+    s, n = 32, 128
+    sr, batch_block = MIN_PLUS, None
+    if case == "plus_mul":
+        sr = SEMIRINGS["plus_mul"]
+        rng = np.random.default_rng(29)
+        w = jnp.asarray(rng.uniform(0.0, 0.01, (n, n)).astype(np.float32))
+    elif case == "or_and_packed":
+        sr = LOWERED_SEMIRINGS["or_and_packed"]
+        w = _lowered_data(sr, (n, n), seed=29)
+    elif case == "batched":
+        w, batch_block = _batch(4, n, seed0=29), 2
+    else:
+        w = _graph(n, seed=29)
+    kw = dict(block_size=s, bk=16, semiring=sr)
+    for b in (0, 2, n // s - 1):
+        got = fw_round(w, b, batch_block=batch_block, interpret=True, **kw)
+        uncached = fw_round(w, b, batch_block=batch_block, variant="unroll",
+                            interpret=True, **kw)
+        twin = fw_round_ref(w, b, **kw)
+        assert got.dtype == w.dtype
+        assert np.array_equal(np.asarray(got), np.asarray(twin)), f"round {b}"
+        assert np.array_equal(np.asarray(got), np.asarray(uncached))
+        w = got
 
 
 # ------------------------------------------------------------- batch grid
@@ -389,19 +424,52 @@ def test_phase2_fits_block_to_any_n():
 
 # ------------------------------------------------------------ plan-layer model
 def test_plan_fused_model():
-    # scratch bands dominate: 2·s·n + 2·2·s² words, plus the body's live
-    # (s,s) tiles on Mosaic's stack.
+    # scratch bands: 2·s·n + 2·2·s² words, plus the body's live (s,s) tiles
+    # on Mosaic's stack and the fori phase 3's lane cache of s³ words.
     live = plan.LIVE_TILES * 128 * 128
     assert plan.fused_round_vmem_bytes(1024, 128, 32) == (
-        (2 * 128 * 1024 + 4 * 128 * 128 + live) * 4
+        (2 * 128 * 1024 + 4 * 128 * 128 + live + 128 ** 3) * 4
     )
-    # broadcast variant adds the (s, bk, s) product transient.
+    # broadcast variant adds the (s, bk, s) product transient and no cache.
     assert plan.fused_round_vmem_bytes(1024, 128, 32, variant="broadcast") == (
         (2 * 128 * 1024 + 4 * 128 * 128 + live + 128 * 32 * 128) * 4
     )
     assert plan.fused_round_steps(1024, 128) == 8 * 8 + 2 * 8 - 1
     # one read + one write per grid step, (s,s) words each.
     assert plan.fused_round_hbm_bytes(1024, 128) == 2 * 79 * 128 * 128 * 4
+
+
+def test_plan_counts_the_lane_cache():
+    # The fori round's phase 3 keeps s³ 32-bit words per graph (16-bit
+    # storage widens); the other variants keep no cache.
+    s = 128
+    for word in (4, 2):
+        for n in (1024, 16384):
+            fori = plan.fused_round_vmem_bytes(n, s, 32, word=word, batch=3)
+            unroll = plan.fused_round_vmem_bytes(
+                n, s, 32, word=word, variant="unroll", batch=3)
+            assert fori - unroll == 3 * s ** 3 * 4
+        fori = plan.bordered_round_vmem_bytes(384, 256, s, 32, word=word,
+                                              batch=2)
+        unroll = plan.bordered_round_vmem_bytes(
+            384, 256, s, 32, word=word, variant="unroll", batch=2)
+        assert fori - unroll == 2 * s ** 3 * 4
+    assert plan.lane_cache_bytes(s, variant="broadcast") == 0
+    # At n=16384 f32 one graph's round asks about 25 MiB: 17 + 8 of cache.
+    assert plan.fused_round_vmem_bytes(16384, s, 32) == (
+        (2 * s * 16384 + (4 + plan.LIVE_TILES) * s * s) * 4 + s ** 3 * 4)
+
+
+@pytest.mark.parametrize("n,want", [(2048, 8), (4096, 4), (16384, 4)])
+def test_plan_auto_batch_block_fits_budget(n, want):
+    # The fattest divisor of B = 16 whose round, lane cache included,
+    # fits the v5e's 100 MiB scoped-VMEM budget.
+    budget = 100 << 20
+    bb = plan.auto_batch_block(16, n, 128, vmem_budget=budget)
+    assert bb == want
+    assert plan.fused_round_vmem_bytes(n, 128, 32, batch=bb) <= budget
+    fatter = min(d for d in (2, 4, 8, 16) if d > bb)
+    assert plan.fused_round_vmem_bytes(n, 128, 32, batch=fatter) > budget
 
 
 def test_plan_batch_models():
@@ -415,14 +483,19 @@ def test_plan_batch_models():
         2 * plan.fused_round_steps(1024, 128)
     )
     # auto_batch_block: fattest divisor of B under the budget; 1 if nothing
-    # fatter fits; successors doubles the footprint and can halve the block.
+    # fatter fits.  The successor round doubles bands, tiles and live
+    # values but keeps no lane cache, so it plans with its own footprint.
     assert plan.auto_batch_block(16, 128, 32) == 16
     assert plan.auto_batch_block(16, 128, 32, vmem_budget=2 * one) >= 1
+    succ = plan.fused_round_vmem_bytes(1024, 128, 32, successors=True)
+    assert succ == 2 * plan.fused_round_vmem_bytes(
+        1024, 128, 32, variant="unroll")
     tight = plan.auto_batch_block(
         16, 1024, 128, vmem_budget=4 * one, successors=False)
     tight_s = plan.auto_batch_block(
         16, 1024, 128, vmem_budget=4 * one, successors=True)
-    assert tight_s <= tight
+    assert tight == 4
+    assert tight_s == max(d for d in (1, 2, 4, 8, 16) if d * succ <= 4 * one)
     assert 16 % plan.auto_batch_block(16, 1024, 128) == 0
     with pytest.raises(ValueError):
         plan.auto_batch_block(0, 128, 32)
@@ -529,18 +602,20 @@ def test_bf16_ref_round_bitwise():
                           np.asarray(ref, np.float32))
 
 
-@pytest.mark.parametrize("owner", [(-1, -1), (1, 1)], ids=["ghost", "owner"])
+@pytest.mark.parametrize("owner", [(-1, -1), (1, 1), (2, 1)],
+                         ids=["ghost", "owner", "owner_last_row"])
 @pytest.mark.parametrize(
-    "case", ["min_plus_i16", "max_plus_i16", "or_and_packed", "bf16"])
+    "case", ["min_plus_i16", "max_plus_i16", "or_and_packed", "bf16", "f32"])
 def test_bordered_round_lowerings_bitwise(case, owner):
     """The distributed bordered round stays bitwise-equal to its XLA twin
-    through every bandwidth-lean lowering (acceptance criterion)."""
+    through every bandwidth-lean lowering (acceptance criterion), with its
+    three tile rows each relaxed against their own lane cache."""
     s, rows, cols = 32, 96, 64
-    if case == "bf16":
+    if case in ("bf16", "f32"):
         sr = MIN_PLUS
         rng = np.random.default_rng(21)
         w = jnp.asarray(rng.uniform(1, 10, (rows, cols)).astype(np.float32),
-                        jnp.bfloat16)
+                        jnp.bfloat16 if case == "bf16" else jnp.float32)
     else:
         sr = LOWERED_SEMIRINGS[case]
         w = _lowered_data(sr, (rows, cols), seed=21)

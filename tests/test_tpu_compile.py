@@ -76,6 +76,21 @@ def test_fused_round_compiles_f32(one_chip, n):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("B,n,bb", [(16, 2048, 8), (4, 16384, 4)])
+def test_batched_round_compiles_at_auto_batch_block(one_chip, B, n, bb):
+    # The batch block auto_batch_block plans, lane cache included, is one
+    # the v5e's scoped VMEM holds.
+    from repro.apsp import plan
+
+    assert plan.auto_batch_block(B, n, 128) == bb
+    text = _compiled_text(
+        lambda w, b: fw_round(w, b, interpret=False),
+        _spec((B, n, n), jnp.float32, one_chip),
+        _spec((), jnp.int32, one_chip),
+    )
+    assert "tpu_custom_call" in text
+
+
 @pytest.mark.parametrize(
     "dtype,semiring",
     [(jnp.bfloat16, MIN_PLUS), (jnp.int32, OR_AND_PACKED)],
