@@ -101,11 +101,11 @@ def solve_phase(n: int, seed: int, *, expect_backend: str = "tpu") -> None:
           f"(method={res.method}, block_size={res.block_size})")
     wd = jnp.asarray(w)
     if expect_backend == "tpu":
-        # The same jitted program solve ran (same static arguments).
+        # The staged program the solve ran, lowered on its own.
         t0 = time.perf_counter()
         text = fw_staged.lower(
             wd, block_size=res.block_size, semiring=MIN_PLUS, variant="fori",
-            interpret=None, fused=None,
+            interpret=None, lowering="pallas",
         ).compile().as_text()
         print(f"  compile (cached program) {time.perf_counter() - t0:.2f}s")
         check("tpu_custom_call" in text,

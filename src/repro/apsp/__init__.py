@@ -7,11 +7,12 @@
     eng = ApspEngine()                   # serving sessions: repeated solves
     results = eng.solve_many(graphs)     # ragged sizes, bucketed + cached
 
-``api.solve`` is the stateless entry point over the paper's implementation
+``engine.ApspEngine`` runs every solve over the paper's implementation
 ladder (numpy / naive / blocked / staged / fused / recursive /
-distributed); ``engine.ApspEngine`` owns the plan/executable cache and
-ragged-batch bucketing for repeated solves (mesh-keyed for distributed
-meshes); ``plan`` holds the shared block-size / padding / roofline /
+distributed) and owns the plan/executable cache and ragged-batch
+bucketing for repeated solves (mesh-keyed for distributed meshes);
+``api.solve`` is the stateless entry point, one ``ApspEngine.solve`` per
+call; ``plan`` holds the shared block-size / padding / roofline /
 autotune / mesh arithmetic (batch-aware).  ``autotune_fw``,
 ``distributed_plan``, and ``recursive_plan`` are re-exported from ``plan``
 as the planner entry points users reach for directly; ``kleene`` holds the

@@ -1,7 +1,10 @@
-"""Unified APSP front-end: ``solve`` owns padding, dispatch, and batching.
+"""Unified APSP front-end: ``solve`` is one call over the engine.
 
 Every caller used to hand-roll the same steps: pad n to a tile multiple,
-pick a method and block size, run, unpad, verify.  ``solve`` owns all of it:
+pick a method and block size, run, unpad, verify.  ``solve`` does all of it
+in one call by building an ``engine.ApspEngine`` from its options and
+running ``ApspEngine.solve`` once, so a stateless solve runs exactly the
+program an engine runs:
 
   * **pad/unpad** — arbitrary n; padding vertices are ⊕-identity rows/cols
     with ⊗-identity diagonal, so they are unreachable under any semiring and
@@ -19,14 +22,15 @@ pick a method and block size, run, unpad, verify.  ``solve`` owns all of it:
     solves bit-for-bit.
   * **successors** — ``successors=True`` tracks next-hop matrices natively
     through the fused round kernel (``fw_staged_with_successors``) or the
-    blocked/naive paths; no more fused→blocked fallback.
+    blocked/naive paths.
   * **validation** — min-plus solves raise ``NegativeCycleError`` when the
     result certifies a negative cycle (a strictly negative diagonal entry).
+  * **packing** — ``packed=True`` wraps the solve in pack → closure of the
+    int32 bit planes → unpack (``pack_reachability``/``unpack_reachability``).
 
-``solve`` is stateless: every call re-plans and re-pads.  For repeated or
-ragged-batch workloads use ``repro.apsp.engine.ApspEngine``, which caches
-the plan/executable per (n_padded, B, dtype, semiring, method, block dims)
-key and buckets ragged graph sets into padded batches.
+``solve`` keeps no state: every call builds a fresh engine.  For repeated
+or ragged-batch workloads keep one ``ApspEngine``, which caches the
+plan/executable per key and buckets ragged graph sets into padded batches.
 """
 from __future__ import annotations
 
@@ -34,88 +38,17 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from repro.apsp import plan
-from repro.apsp.kleene import fw_kleene
-from repro.core.floyd_warshall import fw_blocked, fw_naive, fw_numpy
-from repro.core.paths import fw_blocked_with_successors, fw_with_successors
-from repro.core.semiring import (
-    I16_INF,
-    I16_NINF,
-    LOWERED_SEMIRINGS,
-    MIN_PLUS,
-    PACK_LANES,
-    SEMIRINGS,
-    Semiring,
-    lower_semiring,
+from repro.apsp.engine import (  # noqa: F401  (re-exported public names)
+    METHODS,
+    SUCCESSOR_METHODS,
+    APSPResult,
+    ApspEngine,
+    NegativeCycleError,
+    negative_cycle_mask,
+    pack_edges,
 )
-from repro.core.staged import fw_staged, fw_staged_with_successors
-from repro.utils import compat
-
-METHODS = (
-    "auto", "numpy", "naive", "blocked", "staged", "fused", "recursive",
-    "distributed",
-)
-
-# Methods that can track next-hop successor matrices (min-plus only).
-SUCCESSOR_METHODS = ("naive", "blocked", "staged", "fused")
-
-# Below this size a padded tile pass does more work than the n sweeps of the
-# naive kernel; "auto" stays on the naive rung.
-_NAIVE_CUTOFF = 64
-
-
-class NegativeCycleError(ValueError):
-    """The distance matrix certifies a negative cycle (diag < 0)."""
-
-
-@dataclasses.dataclass(frozen=True)
-class APSPResult:
-    """Outcome of ``solve``: distances plus how they were computed.
-
-    dist: (n, n) or (B, n, n) closure, unpadded.
-    succ: next-hop matrix of the same shape (None unless successors=True);
-          succ[i, j] = -1 where no i→j path exists.
-    backend: the lowering that ran the staged/fused round ("tpu" | "gpu" |
-          "ref"); None for the other methods.
-    """
-
-    dist: jax.Array | np.ndarray
-    succ: jax.Array | np.ndarray | None
-    method: str
-    semiring: str
-    block_size: int | None
-    n: int
-    padded_n: int
-    backend: str | None = None
-
-    @property
-    def batched(self) -> bool:
-        return np.ndim(self.dist) == 3
-
-
-def negative_cycle_mask(dist) -> jax.Array:
-    """Per-graph bool: does the (…, n, n) closure certify a negative cycle?"""
-    diag = jnp.diagonal(jnp.asarray(dist), axis1=-2, axis2=-1)
-    return jnp.any(diag < 0, axis=-1)
-
-
-def _resolve_semiring(semiring: Semiring | str) -> Semiring:
-    if isinstance(semiring, str):
-        sr = SEMIRINGS.get(semiring) or LOWERED_SEMIRINGS.get(semiring)
-        if sr is None:
-            raise ValueError(
-                f"unknown semiring {semiring!r}; have "
-                f"{sorted(SEMIRINGS) + sorted(LOWERED_SEMIRINGS)}"
-            )
-        return sr
-    return semiring
-
-
-def _is_min_plus(sr: Semiring) -> bool:
-    """min_plus or one of its storage lowerings (negative-cycle semantics)."""
-    return sr is MIN_PLUS or sr.name.startswith("min_plus")
+from repro.core.semiring import MIN_PLUS, PACK_LANES, Semiring
 
 
 def pack_reachability(w) -> jax.Array:
@@ -144,63 +77,6 @@ def pack_reachability(w) -> jax.Array:
     return jax.lax.bitcast_convert_type(words, jnp.int32)
 
 
-def pack_edges(src, dst, n: int) -> jax.Array:
-    """Pack per-graph edge lists into the or_and_packed word table.
-
-    src, dst: (B, E) int arrays, or (E,) for one graph: edge ``e`` of graph
-    ``g`` runs ``src[g, e] → dst[g, e]``.  Duplicate edges and self-loops
-    are legal; an edge with an endpoint outside [0, n) is dropped.  Returns
-    the (ceil(B/32), n, n) int32 words of ``pack_reachability``'s layout
-    (graph g at word g // 32, bit g % 32) with every graph's diagonal bit
-    set (the ⊗-identity, -1, in a word of 32 graphs), so the table is ready
-    for the packed closure.
-
-    Peak memory is the words plus O(B·E): each graph's edges are sorted to
-    drop repeats, after which the bits landing on one word entry are
-    distinct and a scatter-add of them is their OR.  No (B, n, n) array is
-    made.  Traceable; ``ApspEngine.pack_edges`` runs it jitted.
-    """
-    src = jnp.asarray(src)
-    dst = jnp.asarray(dst)
-    if src.ndim == 1:
-        src, dst = src[None], dst[None]
-    if src.ndim != 2 or src.shape != dst.shape or src.shape[0] < 1:
-        raise ValueError(
-            f"src and dst must both be (E,) or (B, E) with B >= 1, got "
-            f"{src.shape} and {dst.shape}"
-        )
-    if not (jnp.issubdtype(src.dtype, jnp.integer)
-            and jnp.issubdtype(dst.dtype, jnp.integer)):
-        raise ValueError(
-            f"src and dst must be integer vertex ids, got {src.dtype} and "
-            f"{dst.dtype}"
-        )
-    B = src.shape[0]
-    G = -(-B // PACK_LANES)
-    s, d = jax.lax.sort(
-        (src.astype(jnp.int32), dst.astype(jnp.int32)), dimension=1,
-        num_keys=2,
-    )
-    repeat = jnp.zeros_like(s, dtype=bool).at[:, 1:].set(
-        (s[:, 1:] == s[:, :-1]) & (d[:, 1:] == d[:, :-1])
-    )
-    inside = (s >= 0) & (s < n) & (d >= 0) & (d < n)
-    g = jnp.arange(B, dtype=jnp.int32)[:, None]
-    bit = jnp.left_shift(jnp.int32(1), g % PACK_LANES)
-    bit = jnp.where(repeat | ~inside, 0, bit)
-    words = jnp.zeros((G, n, n), jnp.int32).at[
-        jnp.broadcast_to(g // PACK_LANES, s.shape),
-        jnp.clip(s, 0, n - 1), jnp.clip(d, 0, n - 1),
-    ].add(bit)
-    # Every graph's diagonal holds its ⊗-identity bit; lanes past B stay
-    # empty graphs, as in pack_reachability.
-    lanes = jnp.minimum(B - PACK_LANES * jnp.arange(G), PACK_LANES)
-    diag = jnp.where(lanes == PACK_LANES, -1,
-                     jnp.left_shift(1, lanes % PACK_LANES) - 1)
-    idx = jnp.arange(n)
-    return words.at[:, idx, idx].set(diag.astype(jnp.int32)[:, None])
-
-
 def unpack_reachability(p, count: int | None = None, *, dtype=jnp.float32):
     """Inverse of ``pack_reachability``: (G, n, n) int32 words → (count, n, n)
     0/1 matrices of ``dtype`` (count defaults to all G·32 bit lanes)."""
@@ -215,158 +91,6 @@ def unpack_reachability(p, count: int | None = None, *, dtype=jnp.float32):
     bits = (words[:, None, :, :] >> shifts) & jnp.uint32(1)
     out = bits.reshape(G * PACK_LANES, n, n).astype(dtype)
     return out if count is None else out[:count]
-
-
-def _resolve_method(method: str, n: int, successors: bool) -> str:
-    if method not in METHODS:
-        raise ValueError(f"unknown method {method!r}; have {METHODS}")
-    if method != "auto":
-        return method
-    if n <= _NAIVE_CUTOFF:
-        return "naive"
-    # The Pallas kernels run natively on TPU; on CPU they interpret (slow),
-    # so auto prefers the jnp blocked path there.  The same split applies to
-    # successor tracking: fused-with-successors on TPU, blocked on CPU.
-    if successors:
-        return "fused" if jax.default_backend() == "tpu" else "blocked"
-    return "staged" if jax.default_backend() == "tpu" else "blocked"
-
-
-def _resolve_shape(
-    method: str, n: int, successors: bool, block_size: int | None,
-    *, mesh=None, row_axes="data", col_axes="model",
-    hbm_budget: int | None = None, batch: int = 1, word: int = 4,
-) -> tuple[str, int | None, int]:
-    """(method, block_size, n_padded) — THE dispatch-and-padding policy.
-
-    Shared by the stateless ``solve`` and the engine's plan/bucket keys so
-    the two can never pad or dispatch differently for the same input.  For
-    method="distributed" the padding multiple depends on the mesh grid, not
-    just the tile size: with a mesh it routes through
-    ``plan.distributed_plan`` (auto-padding to the mesh multiple); without
-    one it returns n unchanged and the caller raises.  ``hbm_budget``
-    (device bytes) promotes any in-core tiled method to "recursive" when
-    the padded matrix (batch · m² · word bytes) would not fit — recursive
-    pads identically to fused at the same block size, so the promotion
-    never changes the padded shape, only the schedule.
-    """
-    meth = _resolve_method(method, n, successors)
-    if meth == "distributed" and mesh is not None:
-        from repro.core.distributed import _axis_size
-
-        R = _axis_size(mesh, row_axes)
-        C = _axis_size(mesh, col_axes)
-        dp = plan.distributed_plan(
-            n, R * C, grid=(R, C), block_size=block_size
-        )
-        return meth, dp["block_size"], dp["n_padded"]
-    if meth in ("blocked", "staged", "fused", "recursive"):
-        s = block_size or plan.auto_block_size(n)
-        m = plan.padded_size(n, s)
-        if (
-            meth != "recursive"
-            and not successors
-            and hbm_budget is not None
-            and batch * m * m * word > hbm_budget
-        ):
-            meth = "recursive"
-        return meth, s, m
-    return meth, None, n
-
-
-def _coerce(w, semiring: Semiring, dtype=None):
-    """np/jnp coercion + storage-dtype encoding shared by solve and the engine.
-
-    * Dtype-pinned lowerings encode up front: int16 tropical clips weights
-      into [I16_NINF, I16_INF] (so ±inf lands exactly on the sentinels and
-      out-of-range weights saturate, never wrap); the packed or_and lowering
-      requires pre-packed int32/uint32 bit-plane words (``pack_reachability``
-      or ``solve(packed=True)``).
-    * An explicit float ``dtype`` (bf16/f32/f64) is a plain cast — ±inf is
-      representable, so no re-encoding is needed.
-    * Otherwise, integer matrices cannot represent the ±inf identities of
-      the tropical semirings: padding / missing edges would wrap on ⊗
-      (INT_MAX + w < 0) and silently shorten paths.  Promote once, up front.
-    """
-    arr = np.asarray(w) if isinstance(w, (np.ndarray, list, tuple)) else w
-    if arr.ndim not in (2, 3) or arr.shape[-1] != arr.shape[-2]:
-        raise ValueError(f"w must be (n,n) or (B,n,n), got {arr.shape}")
-    if semiring.packed:
-        if not jnp.issubdtype(arr.dtype, jnp.integer):
-            raise ValueError(
-                f"semiring {semiring.name!r} takes int32 bit-plane words, "
-                f"got {arr.dtype}; pack boolean graphs with "
-                f"pack_reachability() or call solve(..., packed=True)"
-            )
-        if arr.dtype == np.uint32:
-            # Bit-pattern reinterpret, not a value cast (bit 31 is graph 31).
-            arr = (
-                arr.view(np.int32) if isinstance(arr, np.ndarray)
-                else jax.lax.bitcast_convert_type(arr, jnp.int32)
-            )
-        elif arr.dtype != np.int32:
-            arr = arr.astype(jnp.int32)
-        return arr
-    if semiring.dtype == "int16":
-        xp = np if isinstance(arr, np.ndarray) else jnp
-        return xp.clip(arr, I16_NINF, I16_INF).astype(xp.int16)
-    if dtype is not None:
-        return jnp.asarray(arr).astype(dtype)
-    if not jnp.issubdtype(arr.dtype, jnp.floating) and not (
-        np.isfinite(semiring.zero) and np.isfinite(semiring.one)
-    ):
-        arr = arr.astype(np.float32)
-    return arr
-
-
-def _pad(w: jax.Array, m: int, semiring: Semiring) -> jax.Array:
-    """Pad (…, n, n) to (…, m, m) with ⊕-identity edges, ⊗-identity diag.
-
-    A numpy input pads on the host (the distributed solve then places it
-    straight into its sharding, never whole on one device)."""
-    n = w.shape[-1]
-    if m == n:
-        return w
-    if isinstance(w, np.ndarray):
-        out = np.full(w.shape[:-2] + (m, m), semiring.zero, w.dtype)
-        out[..., :n, :n] = w
-        idx = np.arange(n, m)
-        out[..., idx, idx] = semiring.one
-        return out
-    widths = [(0, 0)] * (w.ndim - 2) + [(0, m - n), (0, m - n)]
-    out = jnp.pad(w, widths, constant_values=semiring.zero)
-    idx = jnp.arange(n, m)
-    return out.at[..., idx, idx].set(jnp.asarray(semiring.one, out.dtype))
-
-
-def _check_negative_cycles(dist, batched: bool) -> None:
-    bad = np.asarray(negative_cycle_mask(dist))
-    if bad.any():
-        which = f"graphs {np.flatnonzero(bad).tolist()}" if batched else "graph"
-        raise NegativeCycleError(f"negative cycle detected in {which}")
-
-
-def _check_successor_args(meth: str, semiring: Semiring) -> None:
-    if semiring is not MIN_PLUS:
-        raise ValueError("successors=True requires the min_plus semiring")
-    if meth not in SUCCESSOR_METHODS:
-        raise ValueError(
-            f"successors=True supports methods {SUCCESSOR_METHODS}, not {meth!r}"
-        )
-
-
-def _resolve_backend(backend: str, interpret: bool | None) -> str:
-    """The solver's backend policy on top of ``compat.resolve_pallas_backend``.
-
-    One historical wrinkle: an *explicit* ``interpret=`` under
-    ``backend="auto"`` has always meant "run the TPU Pallas lowering with
-    that interpret flag" (the tests drive the kernels that way on CPU), so
-    auto only falls back to "ref" when interpret is left unset.
-    """
-    be = compat.resolve_pallas_backend(backend)
-    if backend == "auto" and interpret is not None and be == "ref":
-        be = "tpu"
-    return be
 
 
 def solve(
@@ -399,8 +123,8 @@ def solve(
        identities are non-finite (min-plus & friends) — ints cannot encode
        +inf.
     method: "auto" | "numpy" | "naive" | "blocked" | "staged" | "fused" |
-       "distributed".  "fused" pins the one-pallas_call-per-round kernel
-       ("staged" defaults to it too and falls back per fw_staged);
+       "distributed".  "fused" and "staged" both run the
+       one-pallas_call-per-round kernel (or its backend's twin);
        "distributed" shards W over a device mesh and runs the fused
        *bordered* round per device (``core.distributed``), auto-padding n
        to the mesh multiple via ``plan.distributed_plan`` — batched
@@ -458,141 +182,31 @@ def solve(
     input, unpadded), ``succ`` (int32 or None), plus the resolved method /
     semiring / block_size / padded size for introspection.
     """
-    sr = _resolve_semiring(semiring)
-    if packed:
-        # Pack → closure over int32 bit planes → unpack.  The inner solve is
-        # an ordinary or_and_packed solve; each bit lane is an independent
-        # graph, so the unpacked planes are bitwise equal to B unpacked
-        # solves (tests/test_fw_round.py guards 1..32).
-        if successors:
-            raise ValueError(
-                "successors=True requires min_plus; packed=True is the "
-                "or_and transitive-closure lowering"
-            )
-        sr = lower_semiring(sr, dtype, packed=True)
-        arr = jnp.asarray(w)
-        in_batched = arr.ndim == 3
-        count = arr.shape[0] if in_batched else 1
-        words = pack_reachability(arr)
-        if words.shape[0] == 1:
-            words = words[0]  # keep the single-word case on the 2-D path
-        inner = solve(
-            words, method=method, semiring=sr, block_size=block_size,
-            validate=False, mesh=mesh, row_axes=row_axes, col_axes=col_axes,
-            variant=variant, backend=backend, interpret=interpret,
+    if packed and successors:
+        raise ValueError(
+            "successors=True requires min_plus; packed=True is the "
+            "or_and transitive-closure lowering"
         )
-        dist = unpack_reachability(inner.dist, count=count, dtype=arr.dtype)
-        if not in_batched:
-            dist = dist[0]
-        return dataclasses.replace(inner, dist=dist, n=arr.shape[-1])
-    sr = lower_semiring(sr, dtype)
-    arr = _coerce(w, sr, dtype)
-    batched = arr.ndim == 3
-    n = arr.shape[-1]
-    meth, s, m = _resolve_shape(
-        method, n, successors, block_size,
-        mesh=mesh, row_axes=row_axes, col_axes=col_axes,
-        hbm_budget=hbm_budget, batch=arr.shape[0] if batched else 1,
-        word=np.dtype(arr.dtype).itemsize,
+    engine = ApspEngine(
+        method=method, semiring=semiring, dtype=dtype, packed=packed,
+        block_size=block_size, variant=variant, validate=validate,
+        backend=backend, interpret=interpret, mesh=mesh, row_axes=row_axes,
+        col_axes=col_axes, leaf=leaf, hbm_budget=hbm_budget, devices=devices,
     )
-
-    if successors:
-        _check_successor_args(meth, sr)
-    # Validate eagerly even on paths (blocked/numpy/...) that never reach
-    # the staged round, so a typo'd backend= fails loudly.
-    compat.resolve_pallas_backend(backend)
-    if meth == "distributed" and mesh is None:
-        raise ValueError("method='distributed' requires a mesh")
-    if meth == "numpy" and sr is not MIN_PLUS:
-        raise ValueError("method='numpy' implements min_plus only")
-
-    # --- run ------------------------------------------------------------
-    succ = None
-    be = None
-    if meth == "numpy":
-        dist = (
-            np.stack([fw_numpy(g) for g in arr]) if batched else fw_numpy(arr)
-        )
-    elif meth == "naive":
-        wj = jnp.asarray(arr)
-        if successors:
-            run = fw_with_successors
-            dist, succ = jax.vmap(run)(wj) if batched else run(wj)
-        else:
-            # Batch-rank-agnostic: the (B, n, n) case runs the same fori
-            # loop with a leading batch dim — no vmap wrapper.
-            dist = fw_naive(wj, semiring=sr)
-    else:
-        if meth == "distributed" and isinstance(arr, np.ndarray):
-            wp = _pad(arr, m, sr)  # host-side: fw_distributed shards it
-        else:
-            wp = _pad(jnp.asarray(arr), m, sr)
-        if meth == "blocked":
-            if successors:
-                run = lambda x: fw_blocked_with_successors(x, block_size=s)
-                out = jax.vmap(run)(wp) if batched else run(wp)
-                dist, succ = out
-            else:
-                # Natively batched: fw_blocked slices the (B, m, m) array
-                # directly (leading batch dim), one round loop for all B.
-                dist = fw_blocked(wp, block_size=s, semiring=sr)
-        elif meth in ("staged", "fused"):
-            # Natively batched: a (B, m, m) input threads the kernels'
-            # leading batch grid dimension — one dispatch per round for the
-            # whole batch, not a vmap that replays rounds per graph.  The
-            # resolved backend picks the round lowering: TPU Pallas, the
-            # Triton round, or the bitwise XLA ref twin (what auto lands on
-            # for CPU, where the Pallas interpreter's grid emulation would
-            # dominate wall-clock) — same op chains either way.
-            be = _resolve_backend(backend, interpret)
-            if successors:
-                dist, succ = fw_staged_with_successors(
-                    wp, block_size=s, interpret=interpret,
-                    lowering={"tpu": "pallas", "gpu": "gpu", "ref": "ref"}[be],
-                )
-            else:
-                # "staged" leaves the round lowering to fw_staged (fused by
-                # default); "fused" pins the single-dispatch round kernel.
-                dist = fw_staged(
-                    wp, block_size=s, semiring=sr, variant=variant,
-                    interpret=interpret,
-                    fused={"ref": "ref", "gpu": "gpu"}.get(
-                        be, True if meth == "fused" else None
-                    ),
-                )
-        elif meth == "recursive":
-            # R-Kleene panel schedule: plan picks the leaf and decides
-            # in-core (device store) vs out-of-core (host store + streamed
-            # panels); either way the schedule replays the fused round's
-            # op chains exactly, so the closure is bitwise-equal to
-            # method="fused" at the same block size.
-            rp = plan.recursive_plan(
-                n, leaf=leaf, hbm_budget=hbm_budget, block_size=s,
-                batch=arr.shape[0] if batched else 1, dtype=wp.dtype,
-                variant=variant,
-            )
-            dist = fw_kleene(
-                wp, semiring=sr, block_size=s, leaf=rp["leaf"],
-                variant=variant, out_of_core=rp["out_of_core"],
-                interpret=interpret, devices=devices,
-            )
-        else:  # distributed — the fused bordered round, one dispatch/device
-            from repro.core.distributed import fw_distributed
-
-            # The result stays sharded over the mesh.
-            dist = fw_distributed(
-                wp, mesh, block_size=s, row_axes=row_axes, col_axes=col_axes,
-                semiring=sr, variant=variant, interpret=interpret,
-                fused_lowering="auto" if interpret is None else "pallas",
-            )
-        dist = dist[..., :n, :n]
-        if succ is not None:
-            succ = succ[..., :n, :n]
-
-    if validate and _is_min_plus(sr):
-        _check_negative_cycles(dist, batched)
-
-    return APSPResult(
-        dist=dist, succ=succ, method=meth, semiring=sr.name,
-        block_size=s, n=n, padded_n=m, backend=be,
-    )
+    if not packed:
+        return engine.solve(w, successors=successors)
+    # Pack → closure over int32 bit planes → unpack.  The engine runs an
+    # ordinary or_and_packed solve; each bit lane is an independent graph,
+    # so the unpacked planes are bitwise equal to B unpacked solves
+    # (tests/test_fw_round.py guards 1..32).
+    arr = jnp.asarray(w)
+    in_batched = arr.ndim == 3
+    count = arr.shape[0] if in_batched else 1
+    words = pack_reachability(arr)
+    if words.shape[0] == 1:
+        words = words[0]  # keep the single-word case on the 2-D path
+    inner = engine.solve(words)
+    dist = unpack_reachability(inner.dist, count=count, dtype=arr.dtype)
+    if not in_batched:
+        dist = dist[0]
+    return dataclasses.replace(inner, dist=dist, n=arr.shape[-1])

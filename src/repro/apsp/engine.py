@@ -1,10 +1,13 @@
-"""Batched APSP execution engine: plan/executable cache + ragged bucketing.
+"""Batched APSP execution engine: the one path from a configuration to a
+runner, with a plan/executable cache and ragged bucketing.
 
-``api.solve`` is stateless — every call re-plans, re-pads, and re-enters
-``jax.jit``.  Serving workloads (ROADMAP north star: many users, many
-graphs, repeated solves) look different: the same (n, B, dtype) shapes
-recur thousands of times, and request batches arrive *ragged* (mixed graph
-sizes).  ``ApspEngine`` is the session object for that regime:
+Every solve runs here.  The stateless ``api.solve`` builds an engine from
+its options and calls ``ApspEngine.solve`` once; serving workloads keep
+one engine, because the same (n, B, dtype) shapes recur thousands of times
+and request batches arrive *ragged* (mixed graph sizes).  This module owns
+the dispatch-and-padding policy (``_resolve_shape``), the backend policy
+(``_resolve_backend``), the one choice of round lowering (``_build``), the
+input coercion and padding, and the result type:
 
   * **plan/executable cache** — each distinct
     ``(n_padded, batch, dtype, semiring, method, block dims)`` key is
@@ -45,6 +48,7 @@ serving layer serializes refreshes).
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 from typing import Any, Callable, Sequence
@@ -54,23 +58,293 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.apsp import plan
-from repro.apsp.api import (
-    APSPResult,
-    METHODS,
-    NegativeCycleError,
-    _check_negative_cycles,
-    _check_successor_args,
-    _coerce,
-    _is_min_plus,
-    _pad,
-    _resolve_semiring,
-    _resolve_shape,
-    pack_edges,
-)
 from repro.core.floyd_warshall import fw_blocked, fw_naive, fw_numpy
 from repro.core.paths import fw_blocked_with_successors, fw_with_successors
-from repro.core.semiring import MIN_PLUS, Semiring, lower_semiring
+from repro.core.semiring import (
+    I16_INF,
+    I16_NINF,
+    LOWERED_SEMIRINGS,
+    MIN_PLUS,
+    PACK_LANES,
+    SEMIRINGS,
+    Semiring,
+    lower_semiring,
+)
 from repro.core.staged import fw_staged, fw_staged_with_successors
+from repro.utils import compat
+
+METHODS = (
+    "auto", "numpy", "naive", "blocked", "staged", "fused", "recursive",
+    "distributed",
+)
+
+# Methods that can track next-hop successor matrices (min-plus only).
+SUCCESSOR_METHODS = ("naive", "blocked", "staged", "fused")
+
+# Below this size a padded tile pass does more work than the n sweeps of the
+# naive kernel; "auto" stays on the naive rung.
+_NAIVE_CUTOFF = 64
+
+
+class NegativeCycleError(ValueError):
+    """The distance matrix certifies a negative cycle (diag < 0)."""
+
+
+@dataclasses.dataclass(frozen=True)
+class APSPResult:
+    """Outcome of ``solve``: distances plus how they were computed.
+
+    dist: (n, n) or (B, n, n) closure, unpadded.
+    succ: next-hop matrix of the same shape (None unless successors=True);
+          succ[i, j] = -1 where no i→j path exists.
+    backend: the resolved backend whose lowering ran the staged/fused round
+          ("tpu" | "gpu" | "ref"); None for the other methods.
+    """
+
+    dist: jax.Array | np.ndarray
+    succ: jax.Array | np.ndarray | None
+    method: str
+    semiring: str
+    block_size: int | None
+    n: int
+    padded_n: int
+    backend: str | None = None
+
+    @property
+    def batched(self) -> bool:
+        return np.ndim(self.dist) == 3
+
+
+def negative_cycle_mask(dist) -> jax.Array:
+    """Per-graph bool: does the (…, n, n) closure certify a negative cycle?"""
+    diag = jnp.diagonal(jnp.asarray(dist), axis1=-2, axis2=-1)
+    return jnp.any(diag < 0, axis=-1)
+
+
+def _resolve_semiring(semiring: Semiring | str) -> Semiring:
+    if isinstance(semiring, str):
+        sr = SEMIRINGS.get(semiring) or LOWERED_SEMIRINGS.get(semiring)
+        if sr is None:
+            raise ValueError(
+                f"unknown semiring {semiring!r}; have "
+                f"{sorted(SEMIRINGS) + sorted(LOWERED_SEMIRINGS)}"
+            )
+        return sr
+    return semiring
+
+
+def _is_min_plus(sr: Semiring) -> bool:
+    """min_plus or one of its storage lowerings (negative-cycle semantics)."""
+    return sr is MIN_PLUS or sr.name.startswith("min_plus")
+
+
+def pack_edges(src, dst, n: int) -> jax.Array:
+    """Pack per-graph edge lists into the or_and_packed word table.
+
+    src, dst: (B, E) int arrays, or (E,) for one graph: edge ``e`` of graph
+    ``g`` runs ``src[g, e] → dst[g, e]``.  Duplicate edges and self-loops
+    are legal; an edge with an endpoint outside [0, n) is dropped.  Returns
+    the (ceil(B/32), n, n) int32 words of ``pack_reachability``'s layout
+    (graph g at word g // 32, bit g % 32) with every graph's diagonal bit
+    set (the ⊗-identity, -1, in a word of 32 graphs), so the table is ready
+    for the packed closure.
+
+    Peak memory is the words plus O(B·E): each graph's edges are sorted to
+    drop repeats, after which the bits landing on one word entry are
+    distinct and a scatter-add of them is their OR.  No (B, n, n) array is
+    made.  Traceable; ``ApspEngine.pack_edges`` runs it jitted.
+    """
+    src = jnp.asarray(src)
+    dst = jnp.asarray(dst)
+    if src.ndim == 1:
+        src, dst = src[None], dst[None]
+    if src.ndim != 2 or src.shape != dst.shape or src.shape[0] < 1:
+        raise ValueError(
+            f"src and dst must both be (E,) or (B, E) with B >= 1, got "
+            f"{src.shape} and {dst.shape}"
+        )
+    if not (jnp.issubdtype(src.dtype, jnp.integer)
+            and jnp.issubdtype(dst.dtype, jnp.integer)):
+        raise ValueError(
+            f"src and dst must be integer vertex ids, got {src.dtype} and "
+            f"{dst.dtype}"
+        )
+    B = src.shape[0]
+    G = -(-B // PACK_LANES)
+    s, d = jax.lax.sort(
+        (src.astype(jnp.int32), dst.astype(jnp.int32)), dimension=1,
+        num_keys=2,
+    )
+    repeat = jnp.zeros_like(s, dtype=bool).at[:, 1:].set(
+        (s[:, 1:] == s[:, :-1]) & (d[:, 1:] == d[:, :-1])
+    )
+    inside = (s >= 0) & (s < n) & (d >= 0) & (d < n)
+    g = jnp.arange(B, dtype=jnp.int32)[:, None]
+    bit = jnp.left_shift(jnp.int32(1), g % PACK_LANES)
+    bit = jnp.where(repeat | ~inside, 0, bit)
+    words = jnp.zeros((G, n, n), jnp.int32).at[
+        jnp.broadcast_to(g // PACK_LANES, s.shape),
+        jnp.clip(s, 0, n - 1), jnp.clip(d, 0, n - 1),
+    ].add(bit)
+    # Every graph's diagonal holds its ⊗-identity bit; lanes past B stay
+    # empty graphs, as in pack_reachability.
+    lanes = jnp.minimum(B - PACK_LANES * jnp.arange(G), PACK_LANES)
+    diag = jnp.where(lanes == PACK_LANES, -1,
+                     jnp.left_shift(1, lanes % PACK_LANES) - 1)
+    idx = jnp.arange(n)
+    return words.at[:, idx, idx].set(diag.astype(jnp.int32)[:, None])
+
+
+def _resolve_method(method: str, n: int, successors: bool) -> str:
+    if method not in METHODS:
+        raise ValueError(f"unknown method {method!r}; have {METHODS}")
+    if method != "auto":
+        return method
+    if n <= _NAIVE_CUTOFF:
+        return "naive"
+    # The Pallas kernels run natively on TPU; on CPU they interpret (slow),
+    # so auto prefers the jnp blocked path there.  The same split applies to
+    # successor tracking: fused-with-successors on TPU, blocked on CPU.
+    if successors:
+        return "fused" if jax.default_backend() == "tpu" else "blocked"
+    return "staged" if jax.default_backend() == "tpu" else "blocked"
+
+
+def _resolve_shape(
+    method: str, n: int, successors: bool, block_size: int | None,
+    *, mesh=None, row_axes="data", col_axes="model",
+    hbm_budget: int | None = None, batch: int = 1, word: int = 4,
+) -> tuple[str, int | None, int]:
+    """(method, block_size, n_padded) — THE dispatch-and-padding policy.
+
+    The engine's plan and bucket keys both read it, so a bucket can never
+    pad or dispatch differently from a single solve of the same input.  For
+    method="distributed" the padding multiple depends on the mesh grid, not
+    just the tile size: with a mesh it routes through
+    ``plan.distributed_plan`` (auto-padding to the mesh multiple); without
+    one it returns n unchanged and the caller raises.  ``hbm_budget``
+    (device bytes) promotes any in-core tiled method to "recursive" when
+    the padded matrix (batch · m² · word bytes) would not fit — recursive
+    pads identically to fused at the same block size, so the promotion
+    never changes the padded shape, only the schedule.
+    """
+    meth = _resolve_method(method, n, successors)
+    if meth == "distributed" and mesh is not None:
+        from repro.core.distributed import _axis_size
+
+        R = _axis_size(mesh, row_axes)
+        C = _axis_size(mesh, col_axes)
+        dp = plan.distributed_plan(
+            n, R * C, grid=(R, C), block_size=block_size
+        )
+        return meth, dp["block_size"], dp["n_padded"]
+    if meth in ("blocked", "staged", "fused", "recursive"):
+        s = block_size or plan.auto_block_size(n)
+        m = plan.padded_size(n, s)
+        if (
+            meth != "recursive"
+            and not successors
+            and hbm_budget is not None
+            and batch * m * m * word > hbm_budget
+        ):
+            meth = "recursive"
+        return meth, s, m
+    return meth, None, n
+
+
+def _coerce(w, semiring: Semiring, dtype=None):
+    """np/jnp coercion + storage-dtype encoding of every engine input.
+
+    * Dtype-pinned lowerings encode up front: int16 tropical clips weights
+      into [I16_NINF, I16_INF] (so ±inf lands exactly on the sentinels and
+      out-of-range weights saturate, never wrap); the packed or_and lowering
+      requires pre-packed int32/uint32 bit-plane words (``pack_reachability``
+      or ``solve(packed=True)``).
+    * An explicit float ``dtype`` (bf16/f32/f64) is a plain cast — ±inf is
+      representable, so no re-encoding is needed.
+    * Otherwise, integer matrices cannot represent the ±inf identities of
+      the tropical semirings: padding / missing edges would wrap on ⊗
+      (INT_MAX + w < 0) and silently shorten paths.  Promote once, up front.
+    """
+    arr = np.asarray(w) if isinstance(w, (np.ndarray, list, tuple)) else w
+    if arr.ndim not in (2, 3) or arr.shape[-1] != arr.shape[-2]:
+        raise ValueError(f"w must be (n,n) or (B,n,n), got {arr.shape}")
+    if semiring.packed:
+        if not jnp.issubdtype(arr.dtype, jnp.integer):
+            raise ValueError(
+                f"semiring {semiring.name!r} takes int32 bit-plane words, "
+                f"got {arr.dtype}; pack boolean graphs with "
+                f"pack_reachability() or call solve(..., packed=True)"
+            )
+        if arr.dtype == np.uint32:
+            # Bit-pattern reinterpret, not a value cast (bit 31 is graph 31).
+            arr = (
+                arr.view(np.int32) if isinstance(arr, np.ndarray)
+                else jax.lax.bitcast_convert_type(arr, jnp.int32)
+            )
+        elif arr.dtype != np.int32:
+            arr = arr.astype(jnp.int32)
+        return arr
+    if semiring.dtype == "int16":
+        xp = np if isinstance(arr, np.ndarray) else jnp
+        return xp.clip(arr, I16_NINF, I16_INF).astype(xp.int16)
+    if dtype is not None:
+        return jnp.asarray(arr).astype(dtype)
+    if not jnp.issubdtype(arr.dtype, jnp.floating) and not (
+        np.isfinite(semiring.zero) and np.isfinite(semiring.one)
+    ):
+        arr = arr.astype(np.float32)
+    return arr
+
+
+def _pad(w: jax.Array, m: int, semiring: Semiring) -> jax.Array:
+    """Pad (…, n, n) to (…, m, m) with ⊕-identity edges, ⊗-identity diag.
+
+    A numpy input pads on the host (the distributed solve then places it
+    straight into its sharding, never whole on one device)."""
+    n = w.shape[-1]
+    if m == n:
+        return w
+    if isinstance(w, np.ndarray):
+        out = np.full(w.shape[:-2] + (m, m), semiring.zero, w.dtype)
+        out[..., :n, :n] = w
+        idx = np.arange(n, m)
+        out[..., idx, idx] = semiring.one
+        return out
+    widths = [(0, 0)] * (w.ndim - 2) + [(0, m - n), (0, m - n)]
+    out = jnp.pad(w, widths, constant_values=semiring.zero)
+    idx = jnp.arange(n, m)
+    return out.at[..., idx, idx].set(jnp.asarray(semiring.one, out.dtype))
+
+
+def _check_negative_cycles(dist, batched: bool) -> None:
+    bad = np.asarray(negative_cycle_mask(dist))
+    if bad.any():
+        which = f"graphs {np.flatnonzero(bad).tolist()}" if batched else "graph"
+        raise NegativeCycleError(f"negative cycle detected in {which}")
+
+
+def _check_successor_args(meth: str, semiring: Semiring) -> None:
+    if semiring is not MIN_PLUS:
+        raise ValueError("successors=True requires the min_plus semiring")
+    if meth not in SUCCESSOR_METHODS:
+        raise ValueError(
+            f"successors=True supports methods {SUCCESSOR_METHODS}, not {meth!r}"
+        )
+
+
+def _resolve_backend(backend: str, interpret: bool | None) -> str:
+    """The solver's backend policy on top of ``compat.resolve_pallas_backend``.
+
+    One historical wrinkle: an *explicit* ``interpret=`` under
+    ``backend="auto"`` has always meant "run the TPU Pallas lowering with
+    that interpret flag" (the tests drive the kernels that way on CPU), so
+    auto only falls back to "ref" when interpret is left unset.
+    """
+    be = compat.resolve_pallas_backend(backend)
+    if backend == "auto" and interpret is not None and be == "ref":
+        be = "tpu"
+    return be
 
 
 @dataclasses.dataclass(frozen=True)
@@ -108,6 +382,9 @@ class ExecutablePlan:
     runner: padded (batch, m, m) → padded dist (or (dist, succ)).
     traces: number of times JAX actually traced the runner — stays at 1 for
             a warm cache entry (the no-recompile guarantee tests assert).
+            A single-device solve runner shares its program, and so its
+            trace and compile, with every engine of this process that
+            plans the same one; its count is the program's.
     vmem_bytes / hbm_bytes_per_round: the plan-layer model for the fused
             round at this key (None for non-kernel methods).
     """
@@ -194,17 +471,19 @@ class ApspEngine:
         retracing.  Distributed solves do not track successors.
 
         backend pins the round lowering for the staged/fused methods —
-        "auto" (resolve from the attached hardware, exactly like
-        ``api.solve``), "tpu", "gpu" (the Triton round; interpreted when
-        no GPU is attached), or "ref".  The resolved value is part of
-        every plan key, so engines on different backends never share
+        "auto" (resolve from the attached hardware), "tpu", "gpu" (the
+        Triton round; interpreted when no GPU is attached), or "ref".  It
+        is resolved once, here; ``_build`` maps the resolved value to
+        ``fw_staged``'s ``lowering``.  The resolved value is part of every
+        plan key, so engines on different backends never share
         executables and each backend keeps its own warm-cache no-retrace
         guarantee.
 
         leaf/hbm_budget/devices configure method="recursive" (the R-Kleene
         panel schedule of ``apsp.kleene``): ``hbm_budget`` also promotes
         the in-core tiled methods to recursive whenever the padded matrix
-        would not fit the budget, exactly like ``api.solve``; plan keys
+        would not fit the budget (weighed at the solve's batch and
+        dtype); plan keys
         then carry (leaf, oocore), and the cached entry keeps ONE
         ``KleeneExecutor`` whose jit caches make warm solves retrace
         nothing.
@@ -227,8 +506,6 @@ class ApspEngine:
         self.variant = variant
         self.validate = validate
         self.interpret = interpret
-        from repro.apsp.api import _resolve_backend
-
         self.backend = backend
         self._backend = _resolve_backend(backend, interpret)
         self.vmem_budget = vmem_budget
@@ -257,19 +534,23 @@ class ApspEngine:
     def cache_size(self) -> int:
         return len(self._cache)
 
-    def _resolve_shape(self, n: int, successors: bool) -> tuple[str, int | None, int]:
+    def _resolve_shape(
+        self, n: int, successors: bool, batch: int = 1, dtype=None,
+    ) -> tuple[str, int | None, int]:
         """(method, block_size, n_padded) for an n-vertex graph — delegates
-        to api._resolve_shape, the ONE dispatch-and-padding policy, so the
-        bucket key, the plan key, and stateless ``solve`` can never drift.
-        The hbm_budget promotion is evaluated at batch=1 so bucketing stays
-        a pure function of n (a bucket's batch is unknown until formed)."""
-        word = (
-            jnp.dtype(self.dtype).itemsize if self.dtype is not None else 4
-        )
+        to the module's ``_resolve_shape``, the ONE dispatch-and-padding
+        policy, so the bucket key and the plan key can never drift.  The
+        plan key weighs the hbm_budget promotion at its real batch and
+        dtype; buckets form at batch=1 (a bucket's batch is unknown until
+        formed), which is safe because the promotion never changes the
+        padded shape."""
+        if dtype is None:
+            dtype = jnp.float32 if self.dtype is None else self.dtype
         return _resolve_shape(
             self.method, n, successors, self.block_size,
             mesh=self.mesh, row_axes=self.row_axes, col_axes=self.col_axes,
-            hbm_budget=self.hbm_budget, word=word,
+            hbm_budget=self.hbm_budget, batch=batch,
+            word=jnp.dtype(dtype).itemsize,
         )
 
     def plan_for(
@@ -281,7 +562,7 @@ class ApspEngine:
         successors: bool = False,
     ) -> ExecutablePlan:
         """Resolve (and cache) the executable plan for an (n, batch) solve."""
-        meth, s, m = self._resolve_shape(n, successors)
+        meth, s, m = self._resolve_shape(n, successors, batch, dtype)
         if successors:
             _check_successor_args(meth, self.semiring)
         if meth == "numpy" and self.semiring is not MIN_PLUS:
@@ -359,6 +640,8 @@ class ApspEngine:
         sr = self.semiring
         s, bk, bb = key.block_size, key.bk, key.batch_block
         interpret = self.interpret
+        # The one choice of round lowering: the resolved backend names it.
+        lowering = {"tpu": "pallas", "gpu": "gpu", "ref": "ref"}[key.backend]
 
         if key.method == "numpy":
             def runner(wp):
@@ -380,7 +663,9 @@ class ApspEngine:
                 row_axes=self.row_axes, col_axes=self.col_axes,
                 semiring=sr, backend="fused", bk=bk, variant=self.variant,
                 batch_block=key.batch_block,  # resolved under OUR vmem budget
-                fused_lowering="auto" if interpret is None else "pallas",
+                # The bordered round has no Triton twin: off the TPU
+                # lowering it runs its XLA twin.
+                fused_lowering="pallas" if lowering == "pallas" else "ref",
                 interpret=interpret, batched=True,
             )
             entry = ExecutablePlan(
@@ -440,37 +725,6 @@ class ApspEngine:
             entry.executor = ex  # introspection: depth/steps/byte counters
             return entry
 
-        if key.method == "naive":
-            if key.successors:
-                fn = jax.vmap(fw_with_successors)
-            else:
-                # fw_naive/fw_blocked batch natively over the leading dim.
-                fn = lambda x: fw_naive(x, semiring=sr)
-        elif key.method == "blocked":
-            if key.successors:
-                fn = jax.vmap(
-                    lambda x: fw_blocked_with_successors(x, block_size=s)
-                )
-            else:
-                fn = lambda x: fw_blocked(x, block_size=s, semiring=sr)
-        else:  # staged / fused — the kernels' native batch grid
-            # Same lowering policy as api.solve: the key's resolved backend
-            # picks the round lowering (TPU Pallas / Triton / XLA ref twin).
-            be = key.backend
-            if key.successors:
-                fn = lambda x: fw_staged_with_successors(
-                    x, block_size=s, batch_block=bb, interpret=interpret,
-                    lowering={"tpu": "pallas", "gpu": "gpu", "ref": "ref"}[be],
-                )
-            else:
-                fn = lambda x: fw_staged(
-                    x, block_size=s, bk=bk, batch_block=bb,
-                    variant=self.variant, semiring=sr, interpret=interpret,
-                    fused={"ref": "ref", "gpu": "gpu"}.get(
-                        be, True if key.method == "fused" else None
-                    ),
-                )
-
         entry = ExecutablePlan(key=key, runner=None)
         if key.method in ("staged", "fused"):
             scale = 2 if key.successors else 1
@@ -496,13 +750,9 @@ class ApspEngine:
                     key.n_padded, s, word=word, batch=key.batch,
                 )
 
-        def apsp_solve(wp):
-            # Runs only while JAX traces (i.e. on compile) — the cache-hit
-            # tests assert this counter stays put on repeated keys.
-            entry.traces += 1
-            return fn(wp)
-
-        entry.runner = jax.jit(apsp_solve)
+        entry.runner = _solve_runner(
+            entry, SolveProgram(key, sr, self.variant, interpret, lowering)
+        )
         return entry
 
     # -------------------------------------------------------------- solving
@@ -516,7 +766,13 @@ class ApspEngine:
             entry = self.plan_for(
                 n, B, dtype=arr.dtype, successors=successors
             )
-            wb = jnp.asarray(arr)
+            # The numpy rung runs on the host, and the distributed runner
+            # places a host array straight into its sharding: neither puts
+            # a numpy input whole on one device.
+            host = isinstance(arr, np.ndarray) and entry.key.method in (
+                "numpy", "distributed"
+            )
+            wb = arr if host else jnp.asarray(arr)
             if not batched:
                 wb = wb[None]
             dist, succ = self._run(entry, wb, n)
@@ -591,7 +847,7 @@ class ApspEngine:
         """Edge lists → the packed engine's (G, n, n) int32 word table.
 
         src, dst: (B, E) int32 edge arrays of B ≤ 32·G graphs (or (E,) for
-        one); see ``api.pack_edges`` for the layout.  The table is made on
+        one); see ``pack_edges`` for the layout.  The table is made on
         the device by the program ``apsp_pack`` (module ``jit_apsp_pack``)
         inside the host span ``apsp.pack``, ready for ``solve``.
         """
@@ -1119,16 +1375,84 @@ class ApspEngine:
         return out[..., :n, :n], None
 
     def _result(self, entry: ExecutablePlan, dist, succ, n: int) -> APSPResult:
+        key = entry.key
         return APSPResult(
-            dist=dist, succ=succ, method=entry.key.method,
-            semiring=entry.key.semiring, block_size=entry.key.block_size,
-            n=n, padded_n=entry.key.n_padded,
+            dist=dist, succ=succ, method=key.method, semiring=key.semiring,
+            block_size=key.block_size, n=n, padded_n=key.n_padded,
+            backend=key.backend if key.method in ("staged", "fused") else None,
         )
+
+
+@dataclasses.dataclass(frozen=True)
+class SolveProgram:
+    """What the single-device solve runner of one plan key computes.
+
+    It is a static argument of ``apsp_solve``, so engines that plan equal
+    programs — a stateless ``api.solve`` call builds a fresh engine each
+    time — share one trace and one compile through JAX's own cache.
+    """
+
+    key: PlanKey
+    semiring: Semiring
+    variant: str
+    interpret: bool | None
+    lowering: str  # core.staged.LOWERINGS, from key.backend
+
+    def __call__(self, wp):
+        key, sr, s = self.key, self.semiring, self.key.block_size
+        if key.method == "naive":
+            if key.successors:
+                return jax.vmap(fw_with_successors)(wp)
+            # fw_naive/fw_blocked batch natively over the leading dim.
+            return fw_naive(wp, semiring=sr)
+        if key.method == "blocked":
+            if key.successors:
+                return jax.vmap(
+                    lambda x: fw_blocked_with_successors(x, block_size=s)
+                )(wp)
+            return fw_blocked(wp, block_size=s, semiring=sr)
+        # staged / fused — the kernels' native batch grid
+        if key.successors:
+            return fw_staged_with_successors(
+                wp, block_size=s, batch_block=key.batch_block,
+                interpret=self.interpret, lowering=self.lowering,
+            )
+        return fw_staged(
+            wp, block_size=s, bk=key.bk, batch_block=key.batch_block,
+            variant=self.variant, semiring=sr, interpret=self.interpret,
+            lowering=self.lowering,
+        )
+
+
+# Traces of each solve program in this process (``ExecutablePlan.traces``).
+_SOLVE_TRACES: collections.Counter = collections.Counter()
+
+
+@functools.partial(jax.jit, static_argnames="program")
+def apsp_solve(wp, *, program: SolveProgram):
+    """The single-device solve as one program, named for the device trace
+    (module ``jit_apsp_solve``)."""
+    _SOLVE_TRACES[program] += 1  # runs only while JAX traces
+    return program(wp)
+
+
+def _solve_runner(entry: ExecutablePlan, program: SolveProgram):
+    """``entry``'s runner: ``apsp_solve`` of ``program``, with the
+    program's trace count kept on the entry, and ``lower`` for
+    ahead-of-time compiles."""
+
+    def runner(wp):
+        out = apsp_solve(wp, program=program)
+        entry.traces = _SOLVE_TRACES[program]
+        return out
+
+    runner.lower = functools.partial(apsp_solve.lower, program=program)
+    return runner
 
 
 @functools.partial(jax.jit, static_argnames="n")
 def apsp_pack(src, dst, n: int) -> jax.Array:
-    """``api.pack_edges`` as one program, named for the device trace."""
+    """``pack_edges`` as one program, named for the device trace."""
     return pack_edges(src, dst, n)
 
 

@@ -443,8 +443,8 @@ def fw_kleene(
     matrix, streamed panels) instead of the in-core device store.  Pass an
     explicit ``store`` to keep it — its h2d/d2h byte counters are the
     measured side of the ``plan.recursive_transfer_bytes`` model.  Bitwise
-    equal to ``fw_staged(..., fused=...)`` at the same block size on every
-    semiring lowering (tests/test_kleene.py).
+    equal to ``fw_staged`` at the same block size, whatever its round
+    lowering, on every semiring lowering (tests/test_kleene.py).
     """
     m = w.shape[-1]
     if leaf is None:

@@ -84,69 +84,76 @@ def fw_blocked_with_successors(
     if n % s:
         raise ValueError(f"n={n} not a multiple of block_size={s}")
     succ = _init_successors(w)
+    return jax.lax.fori_loop(
+        0, n // s,
+        lambda b, c: blocked_round_with_successors(*c, b, block_size=s),
+        (w, succ),
+    )
 
-    def round_body(b, carry):
-        w, succ = carry
-        o = b * s
 
-        # Phase 1 — diagonal tile; i, j, k all in the pivot block.
-        diag = jax.lax.dynamic_slice(w, (o, o), (s, s))
-        dsucc = jax.lax.dynamic_slice(succ, (o, o), (s, s))
+def blocked_round_with_successors(w, succ, b, *, block_size: int):
+    """Round b of the blocked 3-phase FW on an (n, n) (dist, succ) pair —
+    the phase-by-phase round the fused successor kernel bit-matches."""
+    n = w.shape[0]
+    s = block_size
+    o = b * s
 
-        def p1(k, c):
-            t, ts = c
-            t, ts = _relax_with_succ(k, t, ts, t, ts, t)
-            return t, ts
+    # Phase 1 — diagonal tile; i, j, k all in the pivot block.
+    diag = jax.lax.dynamic_slice(w, (o, o), (s, s))
+    dsucc = jax.lax.dynamic_slice(succ, (o, o), (s, s))
 
-        diag, dsucc = jax.lax.fori_loop(0, s, p1, (diag, dsucc))
-        w = jax.lax.dynamic_update_slice(w, diag, (o, o))
-        succ = jax.lax.dynamic_update_slice(succ, dsucc, (o, o))
+    def p1(k, c):
+        t, ts = c
+        t, ts = _relax_with_succ(k, t, ts, t, ts, t)
+        return t, ts
 
-        # Phase 2 — row band (s, n): rows i live in the pivot block, so
-        # succ[i,k] is the closed diag succ tile.  Row k of the band feeds
-        # later iterations → k sequential.
-        rband = jax.lax.dynamic_slice(w, (o, 0), (s, n))
-        rsucc = jax.lax.dynamic_slice(succ, (o, 0), (s, n))
+    diag, dsucc = jax.lax.fori_loop(0, s, p1, (diag, dsucc))
+    w = jax.lax.dynamic_update_slice(w, diag, (o, o))
+    succ = jax.lax.dynamic_update_slice(succ, dsucc, (o, o))
 
-        def p2r(k, c):
-            p, ps = c
-            p, ps = _relax_with_succ(k, p, ps, diag, dsucc, p)
-            return p, ps
+    # Phase 2 — row band (s, n): rows i live in the pivot block, so
+    # succ[i,k] is the closed diag succ tile.  Row k of the band feeds
+    # later iterations → k sequential.
+    rband = jax.lax.dynamic_slice(w, (o, 0), (s, n))
+    rsucc = jax.lax.dynamic_slice(succ, (o, 0), (s, n))
 
-        rband, rsucc = jax.lax.fori_loop(0, s, p2r, (rband, rsucc))
-        rband = jax.lax.dynamic_update_slice(rband, diag, (0, o))
-        rsucc = jax.lax.dynamic_update_slice(rsucc, dsucc, (0, o))
+    def p2r(k, c):
+        p, ps = c
+        p, ps = _relax_with_succ(k, p, ps, diag, dsucc, p)
+        return p, ps
 
-        # Phase 2 — column band (n, s): columns k live in the pivot block,
-        # so succ[i,k] is the band's own (evolving) succ column k.
-        cband = jax.lax.dynamic_slice(w, (0, o), (n, s))
-        csucc = jax.lax.dynamic_slice(succ, (0, o), (n, s))
+    rband, rsucc = jax.lax.fori_loop(0, s, p2r, (rband, rsucc))
+    rband = jax.lax.dynamic_update_slice(rband, diag, (0, o))
+    rsucc = jax.lax.dynamic_update_slice(rsucc, dsucc, (0, o))
 
-        def p2c(k, c):
-            p, ps = c
-            p, ps = _relax_with_succ(k, p, ps, p, ps, diag)
-            return p, ps
+    # Phase 2 — column band (n, s): columns k live in the pivot block,
+    # so succ[i,k] is the band's own (evolving) succ column k.
+    cband = jax.lax.dynamic_slice(w, (0, o), (n, s))
+    csucc = jax.lax.dynamic_slice(succ, (0, o), (n, s))
 
-        cband, csucc = jax.lax.fori_loop(0, s, p2c, (cband, csucc))
-        cband = jax.lax.dynamic_update_slice(cband, diag, (o, 0))
-        csucc = jax.lax.dynamic_update_slice(csucc, dsucc, (o, 0))
+    def p2c(k, c):
+        p, ps = c
+        p, ps = _relax_with_succ(k, p, ps, p, ps, diag)
+        return p, ps
 
-        w = jax.lax.dynamic_update_slice(w, rband, (o, 0))
-        succ = jax.lax.dynamic_update_slice(succ, rsucc, (o, 0))
-        w = jax.lax.dynamic_update_slice(w, cband, (0, o))
-        succ = jax.lax.dynamic_update_slice(succ, csucc, (0, o))
+    cband, csucc = jax.lax.fori_loop(0, s, p2c, (cband, csucc))
+    cband = jax.lax.dynamic_update_slice(cband, diag, (o, 0))
+    csucc = jax.lax.dynamic_update_slice(csucc, dsucc, (o, 0))
 
-        # Phase 3 — whole matrix vs the closed bands; succ[i,k] is the succ
-        # column band.  Re-relaxing the pivot bands is a no-op under strict
-        # improvement (they are already closed under k ∈ block).
-        def p3(k, c):
-            wm, sm = c
-            return _relax_with_succ(k, wm, sm, cband, csucc, rband)
+    w = jax.lax.dynamic_update_slice(w, rband, (o, 0))
+    succ = jax.lax.dynamic_update_slice(succ, rsucc, (o, 0))
+    w = jax.lax.dynamic_update_slice(w, cband, (0, o))
+    succ = jax.lax.dynamic_update_slice(succ, csucc, (0, o))
 
-        w, succ = jax.lax.fori_loop(0, s, p3, (w, succ))
-        return w, succ
+    # Phase 3 — whole matrix vs the closed bands; succ[i,k] is the succ
+    # column band.  Re-relaxing the pivot bands is a no-op under strict
+    # improvement (they are already closed under k ∈ block).
+    def p3(k, c):
+        wm, sm = c
+        return _relax_with_succ(k, wm, sm, cband, csucc, rband)
 
-    return jax.lax.fori_loop(0, n // s, round_body, (w, succ))
+    w, succ = jax.lax.fori_loop(0, s, p3, (w, succ))
+    return w, succ
 
 
 def extract_path(succ: np.ndarray, src: int, dst: int, max_len: int | None = None) -> list[int]:

@@ -13,17 +13,24 @@ deliberate no-op (they are already closed under k ∈ block and ⊕ is
 idempotent) which keeps the grid uniform — the TPU analogue of the paper
 keeping all thread blocks identical.
 
-The round itself has two lowerings:
+One argument, ``lowering``, names the implementation that runs a round
+(``LOWERINGS``):
 
-  * ``fused=True`` (the default) — the whole round is ONE ``pallas_call``
+  * ``"pallas"`` (the default) — the whole round is ONE ``pallas_call``
     (``kernels.fw_round``): every program classifies its tile from
     ``program_id`` vs. the pivot index and runs the matching stage, with the
     closed pivot bands staged through VMEM scratch instead of HBM
     round-trips.  1 dispatch/round, no ``dynamic_slice`` band copies.
-  * ``fused=False`` — the original 4-dispatch sequence (phase 1, 2×phase 2,
-    phase 3) with the bands spliced via ``dynamic_update_slice``.
+  * ``"ref"`` — that round's bitwise XLA twin (``kernels.ref``), what the
+    engine runs off the TPU, where the Pallas interpreter's grid emulation
+    would dominate wall-clock.
+  * ``"gpu"`` — the Triton round (``kernels.fw_round_gpu``).
+  * ``"phases"`` — the seed's 4-dispatch round (phase 1, 2×phase 2,
+    phase 3) with the bands spliced via ``dynamic_update_slice``; kept as
+    the bitwise reference the others are tested against.
 
-Both lowerings are **natively batched**: a (B, n, n) input runs every round
+``apsp.engine`` maps the resolved backend to this value in one place.
+Every lowering is **natively batched**: a (B, n, n) input runs every round
 of all B graphs through the kernels' leading batch grid dimension — one
 dispatch per round for the whole batch, NOT a ``vmap`` that replays the
 round loop per graph.  Per-element chains are unchanged, so batched outputs
@@ -32,9 +39,10 @@ are bitwise equal to B separate solves.
 The round loop is a ``jax.lax.fori_loop`` over rounds: the body is traced
 once with a traced block offset, so the jaxpr holds a *constant* number of
 pallas_calls regardless of n — compile time is O(1) in the round count.
-``unroll_rounds=True`` restores the seed's trace-time python loop (and, by
-default, the seed's 4-kernel round).  All four lowerings are bit-identical
-(tests/test_apsp_solve.py, tests/test_fw_round.py).
+``unroll_rounds=True`` restores the seed's trace-time python loop; it
+changes the loop form only, never the lowering.  Every lowering in either
+loop form is bit-identical (tests/test_apsp_solve.py,
+tests/test_fw_round.py).
 
 ``fw_staged_with_successors`` drives the fused successor-tracking round
 (``kernels.fw_round_with_successors``): the same schedule carrying a
@@ -54,12 +62,20 @@ from repro.kernels.fw_round import fw_round, fw_round_with_successors
 from repro.kernels.minplus_matmul import _fit_block, semiring_matmul
 from repro.kernels.ref import _dyn_slice, _dyn_update
 
+# The implementations a round can run under (module docstring).
+LOWERINGS = ("pallas", "ref", "gpu", "phases")
+
+
+def _check_lowering(lowering: str) -> None:
+    if lowering not in LOWERINGS:
+        raise ValueError(f"unknown lowering {lowering!r}; have {LOWERINGS}")
+
 
 @functools.partial(
     jax.jit,
     static_argnames=(
         "block_size", "bm", "bn", "bk", "batch_block", "variant", "semiring",
-        "interpret", "unroll_rounds", "fused",
+        "interpret", "unroll_rounds", "lowering",
     ),
 )
 def fw_staged(
@@ -74,7 +90,7 @@ def fw_staged(
     semiring: Semiring = MIN_PLUS,
     interpret: bool | None = None,
     unroll_rounds: bool = False,
-    fused: bool | None = None,
+    lowering: str = "pallas",
 ) -> jax.Array:
     """Staged blocked FW (the paper's 'Staged Load' implementation).
 
@@ -82,24 +98,18 @@ def fw_staged(
       arbitrary n).  Batched input closes all B graphs with one kernel
       dispatch per round (leading batch grid dimension).
     bm/bn/bk: phase-3 output-tile and staging-depth parameters (the fused
-      round works on (s,s) tiles, so bm/bn only affect ``fused=False``).
+      round works on (s,s) tiles, so bm/bn only affect ``"phases"``).
     batch_block: graphs per grid step of the batched fused round (None →
       the fattest divisor of B that fits the VMEM budget).
     unroll_rounds: trace-time python round loop instead of fori_loop
       (O(n/s) trace size; only useful for trace inspection and tests).
-    fused: one pallas_call per round (kernels.fw_round) vs the 4-dispatch
-      multi-kernel round.  None → fused, except under ``unroll_rounds``
-      which preserves the seed lowering exactly.  ``"ref"`` runs the fused
-      round's execution-grade XLA lowering (``kernels.ref.fw_round_ref``) —
-      what ``solve`` picks on CPU, where the Pallas interpreter's grid
-      emulation would dominate wall-clock.  ``"gpu"`` runs the Triton
-      lowering (``kernels.fw_round_gpu``; ``interpret=None`` there
-      auto-interprets when no GPU is attached).  Outputs are bit-identical
+    lowering: the implementation that runs a round, one of ``LOWERINGS``
+      (module docstring); ``interpret=None`` under ``"gpu"``
+      auto-interprets when no GPU is attached.  Outputs are bit-identical
       across all of them.
     """
-    if fused is None:
-        fused = not unroll_rounds
-    if interpret is None and fused != "gpu":
+    _check_lowering(lowering)
+    if interpret is None and lowering != "gpu":
         from repro.kernels.ops import default_interpret
 
         interpret = default_interpret()
@@ -115,8 +125,8 @@ def fw_staged(
     # Phase-2 band tile must divide the band length (e.g. n=640 → bt=320).
     bt_eff = _fit_block(n, 512)
 
-    if fused:
-        if fused == "ref":
+    if lowering != "phases":
+        if lowering == "ref":
             from repro.kernels.ref import fw_round_ref
 
             def round_body(b, w):
@@ -124,7 +134,7 @@ def fw_staged(
                     w, b, block_size=s, bk=bk_eff, variant=variant,
                     semiring=semiring,
                 )
-        elif fused == "gpu":
+        elif lowering == "gpu":
             from repro.kernels.fw_round_gpu import fw_round_gpu
 
             def round_body(b, w):
@@ -195,13 +205,19 @@ def fw_staged_with_successors(
 
     w: (n,n) or (B,n,n) min-plus distance matrix, n % block_size == 0.
     Returns (dist, succ): succ[..., i, j] = next vertex after i on the
-    shortest i→j path, -1 where no path exists.  One ``pallas_call`` per
-    round for the whole batch (``lowering="ref"`` swaps in the bitwise
-    XLA lowering, for CPU execution; ``lowering="gpu"`` the Triton round);
-    outputs bit-match ``core.paths.fw_blocked_with_successors`` per graph.
+    shortest i→j path, -1 where no path exists.  ``lowering`` takes the
+    values of ``fw_staged``'s: one ``pallas_call`` per round for the whole
+    batch under "pallas"; "ref" swaps in the bitwise XLA lowering, "gpu"
+    the Triton round, and "phases" the phase-by-phase round of
+    ``core.paths.blocked_round_with_successors``.  Outputs bit-match
+    ``core.paths.fw_blocked_with_successors`` per graph.
     """
-    from repro.core.paths import _init_successors
+    from repro.core.paths import (
+        _init_successors,
+        blocked_round_with_successors,
+    )
 
+    _check_lowering(lowering)
     if interpret is None and lowering != "gpu":
         from repro.kernels.ops import default_interpret
 
@@ -219,6 +235,13 @@ def fw_staged_with_successors(
 
         def round_body(b, carry):
             return fw_round_with_successors_ref(*carry, b, block_size=s)
+    elif lowering == "phases":
+        step = functools.partial(blocked_round_with_successors, block_size=s)
+        if w.ndim == 3:
+            step = jax.vmap(step, in_axes=(0, 0, None))
+
+        def round_body(b, carry):
+            return step(*carry, b)
     elif lowering == "gpu":
         from repro.kernels.fw_round_gpu import fw_round_with_successors_gpu
 

@@ -83,7 +83,7 @@ def bench_metrics(mesh, w, sr, *, bs: int, backend: str = "fused",
     import jax.numpy as jnp
 
     from repro.apsp import plan
-    from repro.apsp.api import _pad
+    from repro.apsp.engine import _pad
     from repro.core.distributed import build_fw_shard_fn
 
     n = w.shape[-1]
@@ -439,7 +439,7 @@ def main() -> int:
 def _pad_like(want, m, sr, jnp):
     """Pad the single-device oracle to the distributed padded size for a
     direct-mode bitwise diff (solve-mode results are already unpadded)."""
-    from repro.apsp.api import _pad
+    from repro.apsp.engine import _pad
 
     return _pad(jnp.asarray(want), m, sr)
 

@@ -72,7 +72,7 @@ def stream_once(
     )
     # Re-run through an explicit host store to read the byte counters the
     # stateless solve() does not expose (same executor schedule).
-    from repro.apsp.api import _pad
+    from repro.apsp.engine import _pad
 
     wp = np.asarray(_pad(jnp.asarray(w), m, sr))
     ex = KleeneExecutor(

@@ -9,8 +9,7 @@ The monolithic ``serve/engine.py`` split into:
     serve/routing.py    public ``RoutingEngine`` (thin composition)
 
 Import from those modules directly; this shim keeps the old
-``from repro.serve.engine import RoutingEngine, Engine`` spelling working
-(mirroring the ``apsp/solver.py`` shim pattern).
+``from repro.serve.engine import RoutingEngine, Engine`` spelling working.
 """
 from repro.serve.lm import (  # noqa: F401
     Engine,

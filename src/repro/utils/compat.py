@@ -4,8 +4,8 @@
     (replication checks off: the kernels' outputs are per-device blocks).
   * **backend resolution** for the Pallas kernels: ``resolve_pallas_backend``
     maps a user-facing ``backend=`` argument ("auto" | "tpu" | "gpu" |
-    "ref") to the lowering the solver threads through ``PlanKey`` and
-    ``fw_staged(fused=)``, and ``pallas_tpu`` is the ONE lazy
+    "ref") to the backend the engine keys ``PlanKey`` on and maps to
+    ``fw_staged(lowering=)``, and ``pallas_tpu`` is the ONE lazy
     ``jax.experimental.pallas.tpu`` import the kernels route through.
   * **the device's budgets**: ``vmem_limit_bytes`` (the scoped-VMEM limit
     every TPU kernel compiles with, which is also the planning budget) and

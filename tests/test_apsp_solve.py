@@ -6,14 +6,19 @@ Covers the PR's acceptance surface:
   * fori-loop-driven fw_staged/fw_blocked match the unrolled (seed) round
     loop bit-for-bit on every semiring;
   * blocked-path successor matrices reproduce fw_with_successors;
-  * the fw_staged jaxpr holds a number of pallas_calls independent of n.
+  * the fw_staged jaxpr holds a number of pallas_calls independent of n;
+  * solve() is one ApspEngine.solve, and api.py calls no solve
+    implementation of its own.
 """
+import ast
+import inspect
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.apsp import METHODS, NegativeCycleError, plan, solve
+from repro.apsp import METHODS, ApspEngine, NegativeCycleError, plan, solve
 from repro.core import SEMIRINGS, fw_blocked, fw_naive, fw_staged
 from repro.core.graph import random_digraph
 from repro.core.paths import (
@@ -22,6 +27,7 @@ from repro.core.paths import (
     fw_with_successors,
     path_cost,
 )
+from repro.utils import compat
 
 
 def _graph_for(semiring_name: str, n: int, seed: int) -> np.ndarray:
@@ -134,6 +140,59 @@ def test_solve_rejects_bad_arguments():
         solve(w, method="distributed")  # no mesh
 
 
+# ------------------------------------------------- solve() == ApspEngine
+@pytest.mark.parametrize("method", ["auto", "fused"])
+@pytest.mark.parametrize("case", ["plain", "successors", "batched"])
+def test_solve_equals_engine_solve(method, case):
+    """The stateless solve is one ApspEngine.solve: same closure, same
+    next hops, same resolved fields."""
+    if case == "batched":
+        w = np.stack([random_digraph(70, density=0.4, seed=i) for i in range(3)])
+    else:
+        w = random_digraph(70, density=0.4, seed=5)
+    successors = case == "successors"
+    kw = dict(method=method, block_size=32)
+    got = solve(w, successors=successors, **kw)
+    want = ApspEngine(**kw).solve(w, successors=successors)
+    assert np.array_equal(np.asarray(got.dist), np.asarray(want.dist))
+    if successors:
+        assert np.array_equal(np.asarray(got.succ), np.asarray(want.succ))
+    else:
+        assert got.succ is None and want.succ is None
+    fields = ("method", "semiring", "block_size", "n", "padded_n", "backend")
+    assert [getattr(got, f) for f in fields] == [getattr(want, f) for f in fields]
+    if method == "fused":
+        assert got.backend == compat.resolve_pallas_backend("auto")
+
+
+def test_api_reaches_no_solve_implementation():
+    """api.solve's only path to a closure is ApspEngine.solve, and the
+    engine never imports the front end: the arrows point one way."""
+    import repro.apsp.api as api_mod
+    import repro.apsp.engine as engine_mod
+
+    def names(mod):
+        tree = ast.parse(inspect.getsource(mod))
+        out = set()
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Name):
+                out.add(node.id)
+            elif isinstance(node, ast.Attribute):
+                out.add(node.attr)
+            elif isinstance(node, ast.alias):
+                out.add(node.name)
+            elif isinstance(node, ast.ImportFrom):
+                out.add(node.module)
+        return out
+
+    solvers = {"fw_staged", "fw_staged_with_successors", "fw_kleene",
+               "fw_distributed", "fw_blocked", "fw_naive", "fw_numpy"}
+    api_names = names(api_mod)
+    assert not solvers & api_names
+    assert "ApspEngine" in api_names
+    assert not {"repro.apsp.api", "api"} & names(engine_mod)
+
+
 # ------------------------------------------- fori round loop == seed unroll
 @pytest.mark.parametrize("name", sorted(SEMIRINGS))
 def test_blocked_fori_matches_unrolled_bitwise(name):
@@ -150,7 +209,7 @@ def test_staged_fori_matches_unrolled_bitwise(name):
     w = jnp.asarray(_graph_for(name, 64, seed=13))
     kw = dict(block_size=32, bm=32, bn=32, bk=16, semiring=sr, interpret=True)
     fori = fw_staged(w, **kw)
-    unrolled = fw_staged(w, unroll_rounds=True, **kw)
+    unrolled = fw_staged(w, unroll_rounds=True, lowering="phases", **kw)
     assert np.array_equal(np.asarray(fori), np.asarray(unrolled))
 
 
@@ -182,8 +241,8 @@ def test_trace_size_constant_in_n():
     assert n_small == n_large > 0
     # The seed behavior (python round loop) scales with n — guard the guard:
     # phase 1 + 2×phase 2 + phase 3 per round, one round per 128 pivots.
-    assert _count_pallas_calls(trace(512, unroll_rounds=True)) == 4 * (512 // 128)
-    assert _count_pallas_calls(trace(1024, unroll_rounds=True)) == 4 * (1024 // 128)
+    assert _count_pallas_calls(trace(512, unroll_rounds=True, lowering="phases")) == 4 * (512 // 128)
+    assert _count_pallas_calls(trace(1024, unroll_rounds=True, lowering="phases")) == 4 * (1024 // 128)
 
 
 # ------------------------------------------------------- blocked successors

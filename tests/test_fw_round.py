@@ -1,7 +1,7 @@
 """Fused multi-stage round kernel (kernels.fw_round) acceptance surface.
 
   * bit-identity: the fused one-dispatch round is bitwise equal to the seed
-    4-kernel lowering (``fw_staged(unroll_rounds=True, fused=False)``)
+    4-kernel lowering (``fw_staged(unroll_rounds=True, lowering="phases")``)
     across semirings, dtypes, and round counts — not merely allclose;
   * the batch grid: (B,n,n) inputs through fw_round / the phase kernels /
     fw_staged are bitwise equal to B per-graph runs, for any batch block;
@@ -91,7 +91,7 @@ def test_fused_matches_seed_lowering_bitwise(name):
     w = jnp.asarray(w)
     kw = dict(block_size=32, bm=32, bn=32, bk=16, semiring=sr, interpret=True)
     fused = fw_staged(w, **kw)  # fused fori is the default lowering
-    seed = fw_staged(w, unroll_rounds=True, fused=False, **kw)
+    seed = fw_staged(w, unroll_rounds=True, lowering="phases", **kw)
     assert np.array_equal(np.asarray(fused), np.asarray(seed))
 
 
@@ -101,7 +101,7 @@ def test_fused_bit_identity_dtypes(sr, dtype):
     w = _graph(128, seed=5, dtype=dtype)
     kw = dict(block_size=32, bk=32, semiring=sr, interpret=True)
     fused = fw_staged(w, **kw)
-    seed = fw_staged(w, unroll_rounds=True, fused=False, **kw)
+    seed = fw_staged(w, unroll_rounds=True, lowering="phases", **kw)
     assert fused.dtype == dtype
     assert np.array_equal(np.asarray(fused, np.float32),
                           np.asarray(seed, np.float32))
@@ -207,9 +207,10 @@ def test_fw_staged_batched_bitwise_per_graph(name, fused):
     else:
         wb = _batch(3, 64, seed0=9)
     kw = dict(block_size=32, bm=32, bn=32, bk=16, semiring=sr, interpret=True)
-    batched = fw_staged(wb, fused=fused, **kw)
+    kw["lowering"] = "pallas" if fused else "phases"
+    batched = fw_staged(wb, **kw)
     for i in range(3):
-        single = fw_staged(wb[i], fused=fused, **kw)
+        single = fw_staged(wb[i], **kw)
         assert np.array_equal(np.asarray(batched[i]), np.asarray(single))
 
 
@@ -236,7 +237,7 @@ def test_phase_kernels_batched_bitwise():
 
 
 # ----------------------------------------------- fused successor tracking
-@pytest.mark.parametrize("lowering", ["pallas", "ref"])
+@pytest.mark.parametrize("lowering", ["pallas", "ref", "phases"])
 def test_fused_successors_bit_match_blocked(lowering):
     """The satellite acceptance: the fused successor round == the blocked
     successor path, distances AND next hops, bit for bit."""
@@ -249,7 +250,7 @@ def test_fused_successors_bit_match_blocked(lowering):
     assert np.array_equal(np.asarray(s_got), np.asarray(s_ref))
 
 
-@pytest.mark.parametrize("lowering", ["pallas", "ref"])
+@pytest.mark.parametrize("lowering", ["pallas", "ref", "phases"])
 def test_fused_successors_batched(lowering):
     B, n, s = 3, 64, 32
     wb = _batch(B, n, seed0=6)
@@ -320,7 +321,7 @@ def test_solve_fused_successors_native():
 # ------------------------------------------------- ref (XLA) round lowering
 @pytest.mark.parametrize("name", sorted(SEMIRINGS))
 def test_ref_round_lowering_bitwise(name):
-    """fused="ref" (what solve runs on CPU) == the Pallas interpreter,
+    """lowering="ref" (what solve runs on CPU) == the Pallas interpreter,
     bit for bit, on every semiring."""
     sr = SEMIRINGS[name]
     rng = np.random.default_rng(17)
@@ -335,7 +336,7 @@ def test_ref_round_lowering_bitwise(name):
     w = jnp.asarray(w)
     kw = dict(block_size=32, bk=16, semiring=sr)
     pallas = fw_staged(w, interpret=True, **kw)
-    ref = fw_staged(w, fused="ref", **kw)
+    ref = fw_staged(w, lowering="ref", **kw)
     assert np.array_equal(np.asarray(pallas), np.asarray(ref))
 
 
@@ -363,7 +364,7 @@ def test_single_round_graph():
     # T=1: the whole matrix is the pivot tile; phase 1 + its self-relaxation.
     w = _graph(32, seed=2)
     fused = fw_staged(w, block_size=32, interpret=True)
-    seed = fw_staged(w, block_size=32, unroll_rounds=True, fused=False,
+    seed = fw_staged(w, block_size=32, unroll_rounds=True, lowering="phases",
                      interpret=True)
     assert np.array_equal(np.asarray(fused), np.asarray(seed))
 
@@ -373,6 +374,15 @@ def test_fw_round_rejects_bad_shapes():
         fw_round(jnp.zeros((48, 48)), 0, block_size=32, interpret=True)
     with pytest.raises(ValueError):
         fw_round(jnp.zeros((32, 48)), 0, block_size=16, interpret=True)
+
+
+def test_staged_rejects_unknown_lowering():
+    # One argument names the round's implementation, for both round loops.
+    w = _graph(64, seed=3)
+    with pytest.raises(ValueError, match="unknown lowering"):
+        fw_staged(w, block_size=32, lowering="tpu")
+    with pytest.raises(ValueError, match="unknown lowering"):
+        fw_staged_with_successors(w, block_size=32, lowering="fused")
 
 
 # --------------------------------------------------------- trace/dispatch size
@@ -387,8 +397,8 @@ def test_per_round_dispatch_count_dropped():
 
     rounds = 512 // 128
     # unrolled traces expose the per-round count directly:
-    assert _count_pallas_calls(trace(512, unroll_rounds=True, fused=True)) == rounds
-    assert _count_pallas_calls(trace(512, unroll_rounds=True, fused=False)) == 4 * rounds
+    assert _count_pallas_calls(trace(512, unroll_rounds=True)) == rounds
+    assert _count_pallas_calls(trace(512, unroll_rounds=True, lowering="phases")) == 4 * rounds
     # and the fori lowering holds exactly ONE pallas_call total:
     assert _count_pallas_calls(trace(512)) == 1
     assert _count_pallas_calls(trace(2048)) == 1
@@ -583,9 +593,9 @@ def test_lowered_round_bitwise(name):
     w = _lowered_data(sr, (96, 96), seed=13)
     kw = dict(block_size=32, bk=16, semiring=sr)
     fused = fw_staged(w, interpret=True, **kw)
-    unrolled = fw_staged(w, unroll_rounds=True, fused=False, interpret=True,
+    unrolled = fw_staged(w, unroll_rounds=True, lowering="phases", interpret=True,
                          **kw)
-    ref = fw_staged(w, fused="ref", **kw)
+    ref = fw_staged(w, lowering="ref", **kw)
     assert fused.dtype == w.dtype
     assert np.array_equal(np.asarray(fused), np.asarray(unrolled))
     assert np.array_equal(np.asarray(fused), np.asarray(ref))
@@ -596,7 +606,7 @@ def test_bf16_ref_round_bitwise():
     w = _graph(96, seed=7, dtype=jnp.bfloat16)
     kw = dict(block_size=32, bk=16, semiring=MIN_PLUS)
     pallas = fw_staged(w, interpret=True, **kw)
-    ref = fw_staged(w, fused="ref", **kw)
+    ref = fw_staged(w, lowering="ref", **kw)
     assert pallas.dtype == jnp.bfloat16
     assert np.array_equal(np.asarray(pallas, np.float32),
                           np.asarray(ref, np.float32))

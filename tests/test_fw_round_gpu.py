@@ -208,13 +208,13 @@ def test_gpu_round_rejects_bad_shapes():
 # ------------------------------------------------- staged / solve dispatch
 @pytest.mark.parametrize("name", ["min_plus", "plus_mul"])
 def test_fw_staged_gpu_lowering_bitwise(name):
-    """fw_staged(fused="gpu") — the whole solve loop through the Triton
-    round — == fused="ref", idempotent and non-idempotent ⊕."""
+    """fw_staged(lowering="gpu") — the whole solve loop through the Triton
+    round — == lowering="ref", idempotent and non-idempotent ⊕."""
     sr = SEMIRINGS[name]
     w = _graph(96, seed=17)
     kw = dict(block_size=32, bk=16, semiring=sr)
-    got = fw_staged(w, fused="gpu", interpret=True, **kw)
-    ref = fw_staged(w, fused="ref", **kw)
+    got = fw_staged(w, lowering="gpu", interpret=True, **kw)
+    ref = fw_staged(w, lowering="ref", **kw)
     assert _eq(got, ref)
 
 
@@ -312,7 +312,7 @@ def test_resolve_pallas_backend():
 def test_resolve_backend_interpret_wrinkle():
     """Historical policy: an explicit interpret= under backend="auto" runs
     the TPU lowering (the interpreter), never the ref fallback."""
-    from repro.apsp.api import _resolve_backend
+    from repro.apsp.engine import _resolve_backend
 
     if jax.default_backend() in ("tpu", "gpu", "cuda", "rocm"):
         pytest.skip("wrinkle only observable on a CPU-only host")

@@ -82,7 +82,7 @@ def _bitwise(a, b):
 def test_fw_kleene_bitwise_vs_fused(srname, n, s, leaf):
     sr = SEMIRINGS[srname]
     w = jnp.asarray(_graph(n, seed=7, sr=sr))
-    ref = fw_staged(w, block_size=s, semiring=sr, fused="ref")
+    ref = fw_staged(w, block_size=s, semiring=sr, lowering="ref")
     got = fw_kleene(w, semiring=sr, block_size=s, leaf=leaf)
     assert _bitwise(ref, got)
 
@@ -91,7 +91,7 @@ def test_fw_kleene_bitwise_vs_fused(srname, n, s, leaf):
 def test_fw_kleene_batched_bitwise(srname):
     sr = SEMIRINGS[srname]
     w = jnp.asarray(_graph(96, seed=11, sr=sr, batch=3))
-    ref = fw_staged(w, block_size=32, semiring=sr, fused="ref")
+    ref = fw_staged(w, block_size=32, semiring=sr, lowering="ref")
     got = fw_kleene(w, semiring=sr, block_size=32, leaf=32)
     assert _bitwise(ref, got)
 
@@ -145,7 +145,7 @@ def test_host_store_bitwise_and_transfer_model():
     n, s, leaf = 256, 32, 64
     w = _graph(n, seed=29)
     ref = fw_staged(jnp.asarray(w), block_size=s, semiring=MIN_PLUS,
-                    fused="ref")
+                    lowering="ref")
     ex = KleeneExecutor(semiring=MIN_PLUS, block_size=s, leaf=leaf)
     store = HostPanelStore(w)
     ex.run(store)
@@ -190,7 +190,7 @@ def test_batched_transfer_model_scales():
     h2d, d2h = plan.recursive_transfer_bytes(n, s, leaf // s, batch=B)
     assert store.h2d_bytes == h2d and store.d2h_bytes == d2h
     ref = fw_staged(jnp.asarray(w), block_size=s, semiring=MIN_PLUS,
-                    fused="ref")
+                    lowering="ref")
     assert _bitwise(ref, store.result())
 
 

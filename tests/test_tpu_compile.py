@@ -187,15 +187,16 @@ def test_kleene_contraction_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("fused", [None, False],
+@pytest.mark.parametrize("lowering", ["pallas", "phases"],
                          ids=["fused_round", "four_dispatch"])
-def test_solve_program_compiles(one_chip, fused):
+def test_solve_program_compiles(one_chip, lowering):
     # The whole program solve(method="auto") runs on a TPU at n=16384, and
     # the seed's phase-kernel round (fw_phase1/fw_phase2 + the matmul).
     from repro.core.staged import fw_staged
 
     text = _compiled_text(
-        lambda w: fw_staged(w, block_size=128, fused=fused, interpret=False),
+        lambda w: fw_staged(w, block_size=128, lowering=lowering,
+                            interpret=False),
         _spec((N, N), jnp.float32, one_chip),
     )
     assert "tpu_custom_call" in text
